@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from benchmarks import common
 from repro.core import ivf
-from repro.core.lists import ListStore, base_norms, pack_filter_mask
+from repro.core.lists import ListStore, pack_filter_mask
 from repro.core.pq import PQCodebook
 from repro.engine import rerank as rerank_mod
 from repro.kernels import ops, ref
@@ -246,8 +246,7 @@ def rerank_stage_traffic(q: int = 32, k: int = 10, r: int = 4,
     """HBM bytes-accessed of the exact re-rank STAGE, gathered vs stream.
 
     The gathered path materializes a (Q, R, D) f32 copy of the candidate
-    base rows (norms+GEMM formulation — already free of the broadcast-
-    subtraction intermediate) before top-k; the streamed path
+    base rows before top-k; the streamed path
     (``ops.rerank_stream_topk``) DMAs only the candidate rows out of the
     in-place base and reduces to (Q, k) in VMEM. Compiled-only
     (cost_analysis needs no execution), so this runs at the acceptance
@@ -259,18 +258,17 @@ def rerank_stage_traffic(q: int = 32, k: int = 10, r: int = 4,
     rng = np.random.default_rng(0)
     rr = r * k
     base = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-    norms = base_norms(base)
     qs = jnp.asarray(rng.normal(size=(q, d)).astype(np.float32))
     cand = jnp.asarray(rng.integers(0, n, (q, rr)).astype(np.int32))
     stages = (
         ("gathered", jax.jit(functools.partial(rerank_mod.exact_rerank, k=k)),
-         (base, qs, cand), {"norms": norms}),
+         (base, qs, cand)),
         ("stream", jax.jit(functools.partial(ops.rerank_stream_topk, k=k)),
-         (base, norms, qs, cand), {}),
+         (base, qs, cand)),
     )
     records = []
-    for name, fn, args, kw in stages:
-        cost = xla_cost_dict(fn.lower(*args, **kw).compile())
+    for name, fn, args in stages:
+        cost = xla_cost_dict(fn.lower(*args).compile())
         rec = {"kernel": "rerank_stage", "impl": name, "Q": q, "k": k,
                "r": r, "D": d, "N": n,
                "bytes_accessed": cost.get("bytes accessed", 0.0),

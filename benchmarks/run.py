@@ -43,6 +43,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--jobs", default="",
                     help="comma-separated job subset (default: all)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (fig2, kernel_bench, mutation_bench, persist_bench,
                             serve_bench, table1)

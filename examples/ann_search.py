@@ -9,6 +9,7 @@ import time
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.core import metrics
 from repro.data import vectors
 from repro.engine import SearchEngine, ShardedEngine
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--shards", type=int, default=0,
                     help="also run the shard-parallel path with S shards")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("== unified engine: IVF + HNSW + 4-bit PQ + exact re-rank ==")
     ds = vectors.make_deep_like(n=args.n, nt=max(10_000, args.n // 10),

@@ -14,6 +14,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import metrics
 from repro.data import vectors
 from repro.engine import SearchEngine
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--max-wait-ms", type=float, default=2.0,
                     help="batching window: how long a request waits for co-riders")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("== build engine ==")
     ds = vectors.make_sift_like(n=args.n, nt=max(5_000, args.n // 10), nq=256)

@@ -156,9 +156,10 @@ def encode_rows(centroids: jax.Array, cb: PQCodebook, vecs: np.ndarray
     return assign, packed
 
 
-def _probe_tables(index: IVFIndex, q: jax.Array, probe_ids: jax.Array
+def probe_tables(index: IVFIndex, q: jax.Array, probe_ids: jax.Array
                   ) -> fs.QuantizedLUT:
-    """Residual ADC LUTs for each (query, probe): (Q, P, M, 16) u8."""
+    """Residual ADC LUTs for each (query, probe): (Q, P, M, 16) u8, with the
+    per-(query, probe) dequantization affine. The scan kernels' operand."""
     mu = index.centroids[jnp.maximum(probe_ids, 0)]  # (Q, P, D)
     resid = q[:, None, :] - mu                 # (Q, P, D)
     qq, p, d = resid.shape
@@ -192,7 +193,7 @@ def scan_probes(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
     """
     from repro.kernels import ops  # local import: kernels depend on nothing here
 
-    qlut = _probe_tables(index, q, probe_ids)          # (Q, P, M, 16)
+    qlut = probe_tables(index, q, probe_ids)          # (Q, P, M, 16)
     qq, p = probe_ids.shape
     cap = index.lists.cap
     m = qlut.table_q8.shape[-2]
@@ -255,7 +256,7 @@ def scan_probes_stream(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
     """
     from repro.kernels import ops
 
-    qlut = _probe_tables(index, q, probe_ids)          # (Q, P, M, 16)
+    qlut = probe_tables(index, q, probe_ids)          # (Q, P, M, 16)
     qq, p = probe_ids.shape
     bias_sum = jnp.sum(qlut.bias, axis=-1)             # (Q, P)
     tiles_skipped = None

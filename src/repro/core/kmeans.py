@@ -19,16 +19,19 @@ class KMeansResult(NamedTuple):
     inertia: jax.Array  # () float32 — sum of squared distances
 
 
-def pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
+def pairwise_sqdist(x: jax.Array, c: jax.Array,
+                    precision: jax.lax.Precision | None = None) -> jax.Array:
     """Squared L2 distances (n, k) between rows of x (n, d) and c (k, d).
 
     Uses the ||x||^2 - 2 x.c + ||c||^2 expansion so the inner term is a
-    single matmul (MXU-friendly on TPU).
+    single matmul (MXU-friendly on TPU). ``precision`` is the matmul's: the
+    default may run bf16 passes on a TPU, which is fine for k-means and
+    probe selection; exact ground truth asks for ``Precision.HIGHEST``.
     """
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (n, 1)
     c2 = jnp.sum(c * c, axis=-1)  # (k,)
     # clamp: the expansion can go slightly negative in float32
-    d = x2 - 2.0 * (x @ c.T) + c2[None, :]
+    d = x2 - 2.0 * jnp.matmul(x, c.T, precision=precision) + c2[None, :]
     return jnp.maximum(d, 0.0)
 
 

@@ -91,21 +91,6 @@ class ListStore(NamedTuple):
         return jnp.where(probe_ids >= 0, self.sizes[jnp.maximum(probe_ids, 0)], 0)
 
 
-@jax.jit
-def base_norms(base: jax.Array) -> jax.Array:
-    """Per-row squared norms ``‖x‖²`` of the base vectors: (N, D) -> (N,) f32.
-
-    Precomputed once at engine construction (and per shard by
-    ``partition_base``) so the exact re-rank stage can use the norms+GEMM
-    distance formulation ``(‖q‖² − 2·q·x) + ‖x‖²`` without touching the
-    rows twice — the streaming re-rank kernel gathers only these scalars
-    up front and DMAs the rows themselves in place. The mul + ``axis=-1``
-    sum here is the exact expression ``rerank_kernel.norms_gemm_dists``
-    uses for ``‖q‖²``, keeping every path's rounding identical.
-    """
-    return jnp.sum(base * base, axis=-1)
-
-
 # ---------------------------------------------------------------------------
 # packed filter bitmaps (the filtering contract — docs/filtering.md)
 # ---------------------------------------------------------------------------
@@ -172,6 +157,22 @@ def filter_pass_sizes(store: ListStore, filter_bits: jax.Array) -> jax.Array:
                    axis=-1)
 
 
+# Granules of a derived list capacity. The stream kernels scan the store in
+# place with the largest power-of-two tile (<= 1024, kernels'
+# fastscan_kernel.TILE_N) that divides cap, so cap is rounded to keep that
+# tile lane-aligned (128 = TPU lanes) and, past 1024 rows, a full 1024.
+CAP_ALIGN = 128
+CAP_TILE = 1024
+
+
+def aligned_cap(rows: int) -> int:
+    """Capacity for a list of ``rows`` rows: a multiple of ``CAP_ALIGN`` up
+    to ``CAP_TILE``, a multiple of ``CAP_TILE`` beyond it."""
+    rows = max(1, int(rows))
+    granule = CAP_ALIGN if rows <= CAP_TILE else CAP_TILE
+    return -(-rows // granule) * granule
+
+
 def build_lists(assign: np.ndarray, packed_codes: np.ndarray, *, nlist: int,
                 cap: int | None = None, ids: np.ndarray | None = None,
                 attrs: np.ndarray | None = None) -> ListStore:
@@ -183,7 +184,8 @@ def build_lists(assign: np.ndarray, packed_codes: np.ndarray, *, nlist: int,
     attribute, bucketed alongside the codes (-1 at padded slots) so filter
     bitmaps derived from it stay epoch-consistent with the codes. Overflow
     beyond ``cap`` is dropped, reflected in ``sizes`` (same semantics the
-    IVF build always had).
+    IVF build always had). Without ``cap`` the capacity is the fullest
+    list's count rounded up by ``aligned_cap``.
     """
     assign = np.asarray(assign, np.int64)
     packed = np.asarray(packed_codes, np.uint8)
@@ -191,7 +193,7 @@ def build_lists(assign: np.ndarray, packed_codes: np.ndarray, *, nlist: int,
     gids = np.arange(n, dtype=np.int32) if ids is None else np.asarray(ids, np.int32)
     avals = None if attrs is None else np.asarray(attrs, np.int32)
     counts = np.bincount(assign, minlength=nlist)
-    cap_ = int(cap or max(1, counts.max()))
+    cap_ = int(cap or aligned_cap(counts.max()))
     list_codes = np.zeros((nlist, cap_, mh), np.uint8)
     list_ids = np.full((nlist, cap_), -1, np.int32)
     list_attrs = None if avals is None else np.full((nlist, cap_), -1, np.int32)
@@ -506,7 +508,7 @@ def partition_filter(filter_bits: jax.Array, num_shards: int) -> jax.Array:
 
 
 def partition_base(lists_s: ListStore, base: jax.Array
-                   ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Per-shard base-vector slices + the id->row remap for sharded re-rank.
 
     Each base vector lives in exactly one posting list, hence on exactly one
@@ -522,10 +524,7 @@ def partition_base(lists_s: ListStore, base: jax.Array
       base_s    (S, R, D) f32 — shard-local base rows, zero-padded;
       gids_s    (S, R)    i32 — global id of each local row (-1 = padding);
       local_ids (S, L, cap) i32 — ``lists_s.ids`` remapped to shard-local
-                row indices into ``base_s`` (-1 where ids was -1);
-      norms_s   (S, R)    f32 — ``base_norms`` of each local row (0 at
-                padding), stored alongside the partitioned base so the
-                norms+GEMM re-rank never recomputes them per query.
+                row indices into ``base_s`` (-1 where ids was -1).
 
     R = max over shards of the shard's vector count (static shapes — the
     round-robin list partition keeps shards balanced, so the padding slack
@@ -546,12 +545,5 @@ def partition_base(lists_s: ListStore, base: jax.Array
         base_s[j, :g.size] = base_np[g]
         gids_s[j, :g.size] = g
         local_flat[j][mask[j]] = np.arange(g.size, dtype=np.int32)
-    base_s = jnp.asarray(base_s)
-    # slice the precomputed norms per shard instead of re-deriving from the
-    # sliced rows: gathering from one (N,) base_norms output keeps every
-    # shard's values bitwise identical to the single-host engine's
-    norms = np.asarray(base_norms(jnp.asarray(base_np)))
-    norms_s = np.where(gids_s >= 0, norms[np.maximum(gids_s, 0)],
-                       0.0).astype(np.float32)
-    return (base_s, jnp.asarray(gids_s),
-            jnp.asarray(local_flat.reshape(ids.shape)), jnp.asarray(norms_s))
+    return (jnp.asarray(base_s), jnp.asarray(gids_s),
+            jnp.asarray(local_flat.reshape(ids.shape)))

@@ -38,9 +38,16 @@ def _gmm(rng: np.random.Generator, n: int, d: int, ncl: int, spread: float):
 
 def exact_ground_truth(base: jax.Array, queries: jax.Array, g: int = 10,
                        chunk: int = 512) -> jax.Array:
+    """Brute-force g nearest base ids per query, ascending (Q, g) i32.
+
+    The distance matmul runs at ``Precision.HIGHEST``: recall is scored
+    against these ids, so they must be the f32 neighbours even on a TPU,
+    whose default matmul precision may use bf16 passes.
+    """
     outs = []
     for s in range(0, queries.shape[0], chunk):
-        d = pairwise_sqdist(queries[s:s + chunk], base)
+        d = pairwise_sqdist(queries[s:s + chunk], base,
+                            precision=jax.lax.Precision.HIGHEST)
         _, ids = smallest_k(d, g)
         outs.append(ids)
     return jnp.concatenate(outs, axis=0)

@@ -57,7 +57,7 @@ from repro.core import ivf as ivf_mod
 from repro.core import lists as lists_mod
 from repro.core import topk as topk_mod
 from repro.core.kmeans import pairwise_sqdist
-from repro.core.lists import (base_norms, filter_pass_sizes, filter_words,
+from repro.core.lists import (filter_pass_sizes, filter_words,
                               unpack_filter_mask)
 from repro.engine import rerank as rerank_mod
 # single source of truth for both registries (kernels.ops)
@@ -371,7 +371,7 @@ def make_stats(index: ivf_mod.IVFIndex, probes: jax.Array,
 
 
 def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
-              norms: jax.Array | None, ns_member: jax.Array | None,
+              ns_member: jax.Array | None,
               q: jax.Array, filter_bits: jax.Array | None,
               namespaces: jax.Array | None,
               live_bits: jax.Array | None = None,
@@ -413,7 +413,7 @@ def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
         early_exit=early_exit,
         probe_fill=(MARGIN_PROBE_FILL if probe_policy == "margin" else 1.0))
     vals, out_ids, reranked = rerank_mod.finalize_candidates(
-        flat_d, flat_ids, base, q, k, r, norms=norms, rerank_impl=rerank_impl)
+        flat_d, flat_ids, base, q, k, r, rerank_impl=rerank_impl)
     return SearchResult(dists=vals, ids=out_ids,
                         stats=make_stats(index, probes, reranked, filter_bits,
                                          live_bits, lists_pruned,
@@ -453,7 +453,6 @@ class EngineState(NamedTuple):
 
     index: ivf_mod.IVFIndex
     base: jax.Array | None
-    base_norms: jax.Array | None
     live_bits: jax.Array | None  # packed live-row bitmap; None = no tombstones
     epoch: int                   # bumped on every swap (monotonic, starts 0)
     n_tombstones: int            # tombstoned slots currently held across lists
@@ -476,8 +475,7 @@ class SearchEngine:
     fresh epoch. Everything a search reads lives in one ``EngineState``
     snapshot swapped atomically per mutation, so readers never see a torn
     epoch; ``engine.epoch`` counts the swaps. ``index``/``base``/
-    ``base_norms``/``live_bits`` are read-only views of the current
-    snapshot.
+    ``live_bits`` are read-only views of the current snapshot.
     """
 
     def __init__(self, index: ivf_mod.IVFIndex, *, base: jax.Array | None = None,
@@ -485,15 +483,12 @@ class SearchEngine:
                  config: EngineConfig | None = None, hnsw_m: int = 16,
                  ef_construction: int = 64,
                  namespaces: jax.Array | None = None):
-        # ‖x‖² per base row, computed once: the norms+GEMM re-rank (both
-        # impls) reads these instead of re-deriving norms per query.
         # A store built with tombstones already present (unusual, but
         # partition/compact round-trips allow it) derives its live bitmap
         # here so the first search is already exact.
         n_tomb = int(jnp.sum(lists_mod.tombstone_counts(index.lists)))
         self._state = EngineState(
             index=index, base=base,
-            base_norms=None if base is None else base_norms(base),
             live_bits=(lists_mod.live_filter_bits(index.lists)
                        if n_tomb else None),
             epoch=0, n_tombstones=n_tomb)
@@ -548,10 +543,6 @@ class SearchEngine:
     @property
     def base(self) -> jax.Array | None:
         return self._state.base
-
-    @property
-    def base_norms(self) -> jax.Array | None:
-        return self._state.base_norms
 
     @property
     def live_bits(self) -> jax.Array | None:
@@ -610,7 +601,7 @@ class SearchEngine:
         lacks spare capacity the store is compacted in place (reclaiming
         tombstones) and, if still short, grown to a larger cap — both under
         the same swap; autotune verdicts keyed to the retired cap are
-        dropped. ``base``/``base_norms`` grow and update incrementally
+        dropped. ``base`` grows and updates incrementally
         (zero-padded to 256-row multiples); the engine's namespace table is
         deliberately NOT touched — membership is a list-level property the
         caller owns.
@@ -654,12 +645,13 @@ class SearchEngine:
             if (np.asarray(store.sizes) + incoming > store.cap).any():
                 # compact-then-grow: reclaiming tombstones may already free
                 # enough spare slots; only grow cap when live rows + the
-                # batch genuinely exceed it (padded to a multiple of 8 so
-                # the filter-bitmap width stays exact)
+                # batch genuinely exceed it (to an aligned_cap, so the
+                # filter-bitmap width stays exact and the stream kernels
+                # keep a lane-aligned tile)
                 live = np.asarray(lists_mod.live_counts(store))
                 need = int((live + incoming).max())
                 old_cap = store.cap
-                new_cap = max(old_cap, -(-need // 8) * 8)
+                new_cap = max(old_cap, lists_mod.aligned_cap(need))
                 store = lists_mod.compact_lists(store, cap=new_cap)
                 n_tomb = 0
                 loc = lists_mod.locate_rows(store)
@@ -670,7 +662,7 @@ class SearchEngine:
                 store, assign, packed, ids.astype(np.int32), avals)
             for g, l, s in zip(ids.tolist(), assign.tolist(), slots.tolist()):
                 loc[int(g)] = (int(l), int(s))
-            base, norms = st.base, st.base_norms
+            base = st.base
             if base is not None:
                 need_rows = int(ids.max()) + 1
                 n0 = base.shape[0]
@@ -679,22 +671,15 @@ class SearchEngine:
                     base = jnp.concatenate(
                         [base, jnp.zeros((grown - n0, base.shape[1]),
                                          base.dtype)])
-                    norms = jnp.concatenate(
-                        [norms, jnp.zeros((grown - n0,), norms.dtype)])
                     ops_mod.clear_autotune_cache(kind="rerank", n=n0)
-                rows = jnp.asarray(vecs)
-                gidx = jnp.asarray(ids.astype(np.int32))
-                base = base.at[gidx].set(rows)
-                # same row-wise mul+sum expression as core.lists.base_norms
-                # => bitwise equal to a from-scratch norms pass
-                norms = norms.at[gidx].set(jnp.sum(rows * rows, axis=-1))
+                base = base.at[jnp.asarray(ids.astype(np.int32))].set(
+                    jnp.asarray(vecs))
             if self._wal is not None:
                 # durable before visible: fsync the record, then swap
                 self._wal.log_upsert(ids, vecs, avals)
             self._locator = loc
             self._state = EngineState(
                 index=st.index._replace(lists=store), base=base,
-                base_norms=norms,
                 live_bits=(lists_mod.live_filter_bits(store)
                            if n_tomb else None),
                 epoch=st.epoch + 1, n_tombstones=n_tomb)
@@ -730,7 +715,6 @@ class SearchEngine:
             self._locator = loc
             self._state = EngineState(
                 index=st.index._replace(lists=store), base=st.base,
-                base_norms=st.base_norms,
                 live_bits=lists_mod.live_filter_bits(store),
                 epoch=st.epoch + 1,
                 n_tombstones=st.n_tombstones + len(found))
@@ -759,7 +743,7 @@ class SearchEngine:
             self._locator = lists_mod.locate_rows(store)
             self._state = EngineState(
                 index=st.index._replace(lists=store), base=st.base,
-                base_norms=st.base_norms, live_bits=None,
+                live_bits=None,
                 epoch=st.epoch + 1, n_tombstones=0)
             return reclaimed
 
@@ -882,7 +866,7 @@ class SearchEngine:
         q, nprobe, r, fb, ns, tau = self._resolve(
             queries, nprobe, rerank_mult, filter_bits, namespaces, st,
             margin_tau)
-        return _pipeline(self.coarse, st.index, st.base, st.base_norms,
+        return _pipeline(self.coarse, st.index, st.base,
                          self.ns_member if ns is not None else None,
                          q, fb, ns, st.live_bits, tau, k=k, nprobe=nprobe,
                          r=r, scan_impl=self.config.scan_impl,
@@ -923,7 +907,6 @@ class SearchEngine:
                                filter_bits=fb, namespaces=ns,
                                margin_tau=margin_tau)
         return _fused_pipeline(self.coarse, st.index, st.base,
-                               st.base_norms,
                                self.ns_member if ns is not None else None,
                                q, fb, ns, st.live_bits, tau, k=k,
                                nprobe=nprobe, r=r,

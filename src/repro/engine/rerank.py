@@ -11,9 +11,7 @@ Two implementations, selected by ``rerank_impl`` (registry:
 ``kernels.ops.RERANK_IMPLS``), bit-identical through every search path:
 
   'gathered'  gather the candidate rows to a (Q, R, D) copy and compute
-              distances with the norms+GEMM formulation
-              ``(‖q‖² − 2·q·x) + ‖x‖²`` — no broadcast-subtraction
-              intermediate, the dot contracts on the MXU;
+              ``Σ(q − x)²`` over it;
   'stream'    gather-free: the Pallas kernel ``kernels.rerank_kernel``
               DMAs only the candidate rows out of the in-place HBM base
               (double-buffered) and reduces to the final top-k in VMEM, so
@@ -21,10 +19,10 @@ Two implementations, selected by ``rerank_impl`` (registry:
   'auto'      timed dispatch between the two, cached alongside the scan
               verdicts (``kernels.ops.resolve_rerank_impl``).
 
-Both use precomputed per-row base norms (``core.lists.base_norms``) and the
-shared distance helper ``rerank_kernel.norms_gemm_dists``, which is what
-keeps them bit-identical (see that module's docstring for the rounding
-argument).
+Both use the shared distance helper ``rerank_kernel.sq_l2_dists``, which
+is what keeps them bit-identical (see that module's docstring for the
+rounding argument, and for why the subtraction form rather than the
+expanded ``(‖q‖² − 2·q·x) + ‖x‖²``).
 """
 from __future__ import annotations
 
@@ -34,41 +32,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import topk as topk_mod
-from repro.core.lists import base_norms
 from repro.kernels import ops
-from repro.kernels.rerank_kernel import norms_gemm_dists
+from repro.kernels.rerank_kernel import sq_l2_dists
 
 
 @jax.jit
-def exact_distances(base: jax.Array, q: jax.Array, cand_ids: jax.Array,
-                    norms: jax.Array | None = None) -> jax.Array:
-    """True squared-L2 from each query to its candidates, via norms+GEMM.
+def exact_distances(base: jax.Array, q: jax.Array, cand_ids: jax.Array
+                    ) -> jax.Array:
+    """True squared-L2 ``Σ(q − x)²`` from each query to its candidates.
 
-    base: (N, D); q: (Q, D); cand_ids: (Q, R) int32, -1 = padding;
-    norms: optional precomputed ``base_norms(base)`` (N,) f32 (derived here
-    when absent — engines pass their cached copy).
-    Returns (Q, R) f32 with +inf at padded slots.
-
-    ``d = (‖q‖² − 2·q·x) + ‖x‖²`` instead of ``Σ(q − x)²``: algebraically
-    equal, but the row norms come precomputed, the dot is a GEMM, and no
-    (Q, R, D) broadcast-subtraction intermediate is materialized — only the
-    row gather itself remains (the 'stream' impl removes that too).
-    Guarded by a tolerance-zero parity test against the subtraction form on
-    integer-valued data, where f32 arithmetic is exact for both
-    (tests/test_stream_rerank.py).
+    base: (N, D); q: (Q, D); cand_ids: (Q, R) int32, -1 = padding.
+    Returns (Q, R) f32 with +inf at padded slots. The row gather is this
+    path's memory traffic; the 'stream' impl removes it.
     """
-    if norms is None:
-        norms = base_norms(base)
     safe = jnp.maximum(cand_ids, 0)
     vecs = base[safe]                                      # (Q, R, D)
-    d = norms_gemm_dists(q, vecs, norms[safe])             # (Q, R)
+    d = sq_l2_dists(q, vecs)                               # (Q, R)
     return jnp.where(cand_ids >= 0, d, jnp.inf)
 
 
 def finalize_candidates(flat_d: jax.Array, flat_ids: jax.Array,
                         base: jax.Array | None, q: jax.Array, k: int, r: int,
-                        *, norms: jax.Array | None = None,
-                        rerank_impl: str = "gathered"
+                        *, rerank_impl: str = "gathered"
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Stages 3+4 for one candidate pool: optional exact re-rank, final top-k.
 
@@ -87,12 +72,10 @@ def finalize_candidates(flat_d: jax.Array, flat_ids: jax.Array,
         impl, tile_r = ops.resolve_rerank_dispatch(
             rerank_impl, flat_d.shape[0], rr, q.shape[-1], k, base.shape[0])
         if impl == "stream":
-            if norms is None:
-                norms = base_norms(base)
-            vals, out_ids = ops.rerank_stream_topk(base, norms, q, cand_ids,
-                                                   k=k, tile_r=tile_r)
+            vals, out_ids = ops.rerank_stream_topk(base, q, cand_ids, k=k,
+                                                   tile_r=tile_r)
         else:
-            vals, out_ids = exact_rerank(base, q, cand_ids, k, norms=norms)
+            vals, out_ids = exact_rerank(base, q, cand_ids, k)
         reranked = jnp.sum((cand_ids >= 0).astype(jnp.int32), axis=1)
     else:
         vals, pos = topk_mod.masked_topk(flat_d, flat_ids >= 0, k)
@@ -102,8 +85,7 @@ def finalize_candidates(flat_d: jax.Array, flat_ids: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def exact_rerank(base: jax.Array, q: jax.Array, cand_ids: jax.Array, k: int,
-                 *, norms: jax.Array | None = None
+def exact_rerank(base: jax.Array, q: jax.Array, cand_ids: jax.Array, k: int
                  ) -> tuple[jax.Array, jax.Array]:
     """Re-rank candidates by true distance, keep the best k (gathered impl).
 
@@ -112,6 +94,6 @@ def exact_rerank(base: jax.Array, q: jax.Array, cand_ids: jax.Array, k: int,
     vector lives in exactly one IVF list), so no dedup pass is needed. The
     semantic oracle the streaming kernel is held bit-identical to.
     """
-    d = exact_distances(base, q, cand_ids, norms)
+    d = exact_distances(base, q, cand_ids)
     vals, pos = topk_mod.masked_topk(d, cand_ids >= 0, k)
     return vals, topk_mod.gather_ids(cand_ids, pos)

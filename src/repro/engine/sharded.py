@@ -21,8 +21,8 @@ Two drivers over the same per-shard function:
 Live mutation (docs/mutability.md) is threaded through the shards:
 ``upsert`` routes rows with the *global* centroid table (bitwise the same
 assignment the single-host engine makes), appends into the owning shard's
-spare slots, and maintains the local-id remap (``gids_s``/``norms_s`` grow
-in place); ``delete`` tombstones slots; ``compact`` rebuilds every shard's
+spare slots, and maintains the local-id remap (``gids_s`` grows in
+place); ``delete`` tombstones slots; ``compact`` rebuilds every shard's
 lists and base slice tombstone-free. All shard arrays live in one
 ``_ShardState`` snapshot swapped atomically per mutation, mirroring the
 single-host ``EngineState``.
@@ -40,9 +40,10 @@ import numpy as np
 from repro.core import ivf as ivf_mod
 from repro.core import topk as topk_mod
 from repro.core.kmeans import pairwise_sqdist
-from repro.core.lists import (ListStore, filter_pass_sizes, pack_filter_mask,
-                              partition_base, partition_filter,
-                              partition_lists, round_robin_perm)
+from repro.core.lists import (ListStore, aligned_cap, filter_pass_sizes,
+                              pack_filter_mask, partition_base,
+                              partition_filter, partition_lists,
+                              round_robin_perm)
 from repro.engine import rerank as rerank_mod
 from repro.engine.engine import (MARGIN_PROBE_FILL, EngineConfig, QueryStats,
                                  SearchEngine, SearchResult,
@@ -53,7 +54,7 @@ AXIS = "shards"
 
 
 def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
-                  norms, member, q, fbits, live, ns, tau, *, k: int,
+                  member, q, fbits, live, ns, tau, *, k: int,
                   nprobe: int, r: int, scan_impl: str, rerank_impl: str,
                   remap: bool, probe_policy: str = "fixed",
                   early_exit: bool = False):
@@ -63,7 +64,7 @@ def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
     ``base`` slice (see ``partition_base``): the scan and re-rank both work
     on local ids and ``gids`` translates back to global just before the
     distributed merge. With ``remap=False`` (no base held) ids are global
-    throughout and ``gids``/``norms`` are unused dummies.
+    throughout and ``gids`` is an unused dummy.
 
     ``member`` is the shard's (n_ns, L) slice of the namespace table,
     ``fbits`` its (L, W) slice of the per-request filter bitmap, ``live``
@@ -109,11 +110,11 @@ def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
         index, q, probes, scan_impl=scan_impl, keep=(r * k) if r else k,
         filter_bits=eff, early_exit=early_exit,
         probe_fill=(MARGIN_PROBE_FILL if probe_policy == "margin" else 1.0))
-    # re-rank (either impl) runs on the shard-local (R, D) base slice with
-    # its precomputed local norms; local candidate ids map back to global
-    # through gids only after the top-k, just before the merge
+    # re-rank (either impl) runs on the shard-local (R, D) base slice; local
+    # candidate ids map back to global through gids only after the top-k,
+    # just before the merge
     vals, out_ids, reranked = rerank_mod.finalize_candidates(
-        flat_d, flat_ids, base, q, k, r, norms=norms, rerank_impl=rerank_impl)
+        flat_d, flat_ids, base, q, k, r, rerank_impl=rerank_impl)
     if remap:
         out_ids = jnp.where(out_ids >= 0, gids[jnp.maximum(out_ids, 0)], -1)
     mvals, mids = topk_mod.distributed_topk(vals, out_ids, k, AXIS)
@@ -148,6 +149,41 @@ def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
     return mvals, mids, stats
 
 
+@functools.lru_cache(maxsize=32)
+def _mesh_driver(mesh: jax.sharding.Mesh, **static):
+    """``_local_search`` with the ``static`` knobs as one jitted
+    ``shard_map`` program over ``mesh``, one shard per device — built once
+    per (mesh, knobs), so a stream of searches compiles once instead of
+    once per call."""
+    from jax.sharding import PartitionSpec as P
+
+    fn = functools.partial(_local_search, **static)
+
+    def per_device(cen, lists, real, gids, cb, base, mem, qq, fb, lv, nss,
+                   tt):
+        # each device owns exactly one shard => leading block dim is 1
+        out_v, out_i, stt = fn(cen[0], jax.tree.map(lambda x: x[0], lists),
+                               real[0], gids[0], cb,
+                               None if base is None else base[0],
+                               None if mem is None else mem[0], qq,
+                               None if fb is None else fb[0],
+                               None if lv is None else lv[0], nss, tt)
+        return (out_v[None], out_i[None],
+                jax.tree.map(lambda x: x[None], stt))
+
+    base_spec = P(AXIS) if static["remap"] else P()
+    return jax.jit(jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(), base_spec,
+                  P(AXIS), P(), P(AXIS), P(AXIS), P(), P()),
+        out_specs=(P(AXIS), P(AXIS), P(AXIS)),
+        # jax has no replication rule for pallas_call (the 'stream'
+        # scan/re-rank kernels); the merge replicates results itself via
+        # all_gather, so skipping the static replication check is sound
+        check_vma=False,
+    ))
+
+
 class _ShardState(NamedTuple):
     """One immutable snapshot of every shard-partitioned array a search
     reads — the sharded twin of ``engine.EngineState`` (docs/mutability.md).
@@ -160,7 +196,6 @@ class _ShardState(NamedTuple):
     real_s: jax.Array             # (S, L) bool — False on padding lists
     base_s: jax.Array | None      # (S, R, D) or None
     gids_s: jax.Array             # (S, R) i32 local row -> global id
-    norms_s: jax.Array | None     # (S, R) f32
     live_s: jax.Array | None      # (S, L, W) u8 live bitmap; None = no tombs
     rows_used: tuple              # per-shard base rows in use (len S)
     epoch: int
@@ -178,9 +213,8 @@ class ShardedEngine:
     When the wrapped engine holds base vectors, they are partitioned by
     shard list-membership (``partition_base``): each shard's re-rank reads
     only its own (R, D) slice, R ~= N/S, instead of a replicated (N, D)
-    copy — with the per-row ‖x‖² norms (``norms_s``) partitioned alongside
-    for the norms+GEMM formulation, so a 'stream' re-rank shard DMAs
-    candidate rows straight out of its local slice. Shard-local ListStore
+    copy, so a 'stream' re-rank shard DMAs candidate rows straight out of
+    its local slice. Shard-local ListStore
     ids become local row indices; ``gids_s`` maps them back to global ids
     after the per-shard pipeline.
 
@@ -204,8 +238,7 @@ class ShardedEngine:
         centroids_s, lists_s, real_s = partition_lists(
             engine.index.lists, engine.index.centroids, self.num_shards)
         if engine.base is not None:
-            base_s, gids_s, local_ids, norms_s = partition_base(
-                lists_s, engine.base)
+            base_s, gids_s, local_ids = partition_base(lists_s, engine.base)
             lists_s = lists_s._replace(ids=local_ids)
             rows_used = tuple(int(c) for c in
                               np.asarray(jnp.sum(gids_s >= 0, axis=1)))
@@ -213,7 +246,6 @@ class ShardedEngine:
             base_s = None
             # unused dummies so both vmap and shard_map see a uniform arity
             gids_s = jnp.full((self.num_shards, 1), -1, jnp.int32)
-            norms_s = None
             rows_used = (0,) * self.num_shards
         # a wrapped engine may already carry tombstones — count them so the
         # first sharded search is already exact
@@ -221,7 +253,7 @@ class ShardedEngine:
                      - np.asarray(jnp.sum(lists_s.ids >= 0)))
         self._state = _ShardState(
             centroids_s=centroids_s, lists_s=lists_s, real_s=real_s,
-            base_s=base_s, gids_s=gids_s, norms_s=norms_s,
+            base_s=base_s, gids_s=gids_s,
             live_s=pack_filter_mask(lists_s.ids >= 0) if n_tomb else None,
             rows_used=rows_used, epoch=0, n_tombstones=n_tomb)
         self._mutate_lock = threading.RLock()
@@ -229,6 +261,9 @@ class ShardedEngine:
         # optional persist.WALWriter, mirroring SearchEngine.attach_wal:
         # mutations are logged + fsync'd before the state swap
         self._wal = None
+        # (snapshot, mesh, placed snapshot, placed namespace table) of the
+        # last mesh search (_on_mesh)
+        self._mesh_copy = None
         # namespace membership sharded with the same round-robin permutation
         # as the lists: shard j's (n_ns, L) slice covers exactly its lists;
         # padding lists are member-False for every namespace
@@ -269,10 +304,6 @@ class ShardedEngine:
     @property
     def gids_s(self) -> jax.Array:
         return self._state.gids_s
-
-    @property
-    def norms_s(self) -> jax.Array | None:
-        return self._state.norms_s
 
     @property
     def live_s(self) -> jax.Array | None:
@@ -385,7 +416,7 @@ class ShardedEngine:
             if (sizes + inc > lists_s.ids.shape[-1]).any():
                 old_cap = lists_s.ids.shape[-1]
                 need = int((sizes + inc).max())
-                new_cap = -(-need // 8) * 8
+                new_cap = aligned_cap(need)
                 pad = new_cap - old_cap
                 s_n = lists_s.ids.shape[0]
                 l_n = lists_s.ids.shape[1]
@@ -413,7 +444,7 @@ class ShardedEngine:
             counts = np.zeros(sizes.shape, np.int32)
             np.add.at(counts, (shard, local), 1)
 
-            base_s, gids_s, norms_s = st.base_s, st.gids_s, st.norms_s
+            base_s, gids_s = st.base_s, st.gids_s
             rows_used = st.rows_used
             if base_s is not None:
                 # shard-local base rows: next free row per shard, in batch
@@ -437,19 +468,12 @@ class ShardedEngine:
                     gids_s = jnp.concatenate(
                         [gids_s, jnp.full((gids_s.shape[0], pad_r), -1,
                                           jnp.int32)], axis=1)
-                    norms_s = jnp.concatenate(
-                        [norms_s, jnp.zeros((norms_s.shape[0], pad_r),
-                                            norms_s.dtype)], axis=1)
                     ops_mod.clear_autotune_cache(kind="rerank", n=old_r)
                 vj = jnp.asarray(shard.astype(np.int32))
                 vr = jnp.asarray(rows.astype(np.int32))
-                vrows = jnp.asarray(vecs)
-                base_s = base_s.at[vj, vr].set(vrows)
+                base_s = base_s.at[vj, vr].set(jnp.asarray(vecs))
                 gids_s = gids_s.at[vj, vr].set(
                     jnp.asarray(ids.astype(np.int32)))
-                # same row-wise mul+sum as core.lists.base_norms
-                norms_s = norms_s.at[vj, vr].set(
-                    jnp.sum(vrows * vrows, axis=-1))
                 np.add.at(used, shard, 1)
                 rows_used = tuple(int(c) for c in used)
                 slot_ids = rows.astype(np.int32)       # local row indices
@@ -480,7 +504,6 @@ class ShardedEngine:
             self._state = _ShardState(
                 centroids_s=st.centroids_s, lists_s=lists_s,
                 real_s=st.real_s, base_s=base_s, gids_s=gids_s,
-                norms_s=norms_s,
                 live_s=(pack_filter_mask(lists_s.ids >= 0)
                         if n_tomb else None),
                 rows_used=rows_used, epoch=st.epoch + 1,
@@ -550,12 +573,10 @@ class ShardedEngine:
             if st.base_s is not None:
                 base = np.asarray(st.base_s)
                 gids = np.asarray(st.gids_s)
-                norms = np.asarray(st.norms_s)
                 r_cap = max(1, -(-int(counts.sum(axis=1).max(initial=1))
                                  // 256) * 256)
                 n_base = np.zeros((s_n, r_cap, base.shape[-1]), base.dtype)
                 n_gids = np.full((s_n, r_cap), -1, np.int32)
-                n_norms = np.zeros((s_n, r_cap), norms.dtype)
             rows_used = []
             for j in range(s_n):
                 cursor = 0
@@ -573,7 +594,6 @@ class ShardedEngine:
                         n_ids[j, l, :c] = new_rows
                         n_base[j, new_rows] = base[j, old_rows]
                         n_gids[j, new_rows] = gids[j, old_rows]
-                        n_norms[j, new_rows] = norms[j, old_rows]
                         cursor += c
                 rows_used.append(cursor)
             lists_s = ListStore(
@@ -593,11 +613,53 @@ class ShardedEngine:
                 lists_s=lists_s,
                 base_s=None if st.base_s is None else jnp.asarray(n_base),
                 gids_s=st.gids_s if st.base_s is None else jnp.asarray(n_gids),
-                norms_s=(None if st.base_s is None
-                         else jnp.asarray(n_norms)),
                 live_s=None, rows_used=tuple(rows_used),
                 epoch=st.epoch + 1, n_tombstones=0)
             return reclaimed
+
+    def _on_mesh(self, st: _ShardState, mesh: jax.sharding.Mesh
+                 ) -> tuple[_ShardState, jax.Array | None]:
+        """``st`` and the namespace table with every shard-partitioned array
+        split over ``mesh``, one shard per device.
+
+        Placed once per (snapshot, mesh) and kept beside the engine state,
+        so a stream of mesh searches finds its shards already resident
+        instead of resharding every call. The state itself stays as built:
+        the vmap driver keeps reading unplaced arrays (arrays placed on an
+        explicit-axis mesh carry their sharding in their type, which the
+        vmap driver's scatters reject).
+        """
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        hit = self._mesh_copy
+        if hit is not None and hit[0] is st and hit[1] == mesh:
+            return hit[2], hit[3]
+        sh = NamedSharding(mesh, P(AXIS))
+
+        def put(x):
+            return None if x is None else jax.device_put(x, sh)
+
+        placed = st._replace(
+            centroids_s=put(st.centroids_s),
+            lists_s=jax.tree.map(put, st.lists_s), real_s=put(st.real_s),
+            base_s=put(st.base_s), gids_s=put(st.gids_s),
+            live_s=put(st.live_s))
+        member_s = put(self.member_s)
+        self._mesh_copy = (st, mesh, placed, member_s)
+        return placed, member_s
+
+    def shard_bytes(self, mesh: jax.sharding.Mesh) -> dict:
+        """Bytes of shard state each device holds for the shard_map driver
+        on ``mesh`` (placing it there if no search on ``mesh`` has yet)."""
+        placed, member_s = self._on_mesh(self._state, mesh)
+        held: dict = {}
+        for leaf in jax.tree.leaves((placed, member_s)):
+            if isinstance(leaf, jax.Array):
+                for shard in leaf.addressable_shards:
+                    held[shard.device] = (held.get(shard.device, 0)
+                                          + shard.data.nbytes)
+        return held
 
     def search(self, queries: jax.Array, k: int = 10, *,
                nprobe: int | None = None, rerank_mult: int | None = None,
@@ -666,61 +728,35 @@ class ShardedEngine:
         else:
             fbits_s = None
         member_s = self.member_s if namespaces is not None else None
-        fn = functools.partial(_local_search, k=k, nprobe=nprobe, r=r,
-                               scan_impl=self.config.scan_impl,
-                               rerank_impl=self.config.rerank_impl,
-                               remap=st.base_s is not None,
-                               probe_policy=self.config.probe_policy,
-                               early_exit=self.config.early_exit)
+        static = dict(k=k, nprobe=nprobe, r=r,
+                      scan_impl=self.config.scan_impl,
+                      rerank_impl=self.config.rerank_impl,
+                      remap=st.base_s is not None,
+                      probe_policy=self.config.probe_policy,
+                      early_exit=self.config.early_exit)
+        fn = functools.partial(_local_search, **static)
         base_ax = 0 if st.base_s is not None else None
 
         if mesh is None:
             # None args are empty pytrees: their in_axes entries are inert
             mvals, mids, stats = jax.vmap(
-                fn, in_axes=(0, 0, 0, 0, None, base_ax, base_ax, 0, None, 0,
-                             0, None, None),
+                fn, in_axes=(0, 0, 0, 0, None, base_ax, 0, None, 0, 0, None,
+                             None),
                 axis_name=AXIS,
             )(st.centroids_s, st.lists_s, st.real_s, st.gids_s,
-              self.codebook, st.base_s, st.norms_s, member_s, q, fbits_s,
-              st.live_s, namespaces, tau)
+              self.codebook, st.base_s, member_s, q, fbits_s, st.live_s,
+              namespaces, tau)
             # merge output is replicated across the shard axis; take shard 0
             return SearchResult(mvals[0], mids[0],
                                 QueryStats(*(s[0] for s in stats)))
-
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
 
         if mesh.shape[AXIS] != self.num_shards:
             raise ValueError(
                 f"mesh axis {AXIS!r} has {mesh.shape[AXIS]} devices but the "
                 f"engine holds {self.num_shards} shards")
-
-        def per_device(cen, lists, real, gids, cb, base, norms, mem, qq, fb,
-                       lv, nss, tt):
-            # each device owns exactly one shard => leading block dim is 1
-            out_v, out_i, stt = fn(cen[0], jax.tree.map(lambda x: x[0], lists),
-                                   real[0], gids[0], cb,
-                                   None if base is None else base[0],
-                                   None if norms is None else norms[0],
-                                   None if mem is None else mem[0], qq,
-                                   None if fb is None else fb[0],
-                                   None if lv is None else lv[0], nss, tt)
-            return (out_v[None], out_i[None],
-                    jax.tree.map(lambda x: x[None], stt))
-
-        base_spec = P() if st.base_s is None else P(AXIS)
-        sharded = shard_map(
-            per_device, mesh=mesh,
-            in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(), base_spec,
-                      base_spec, P(AXIS), P(), P(AXIS), P(AXIS), P(), P()),
-            out_specs=(P(AXIS), P(AXIS), P(AXIS)),
-            # jax has no replication rule for pallas_call (the 'stream'
-            # scan/re-rank kernels); the merge replicates results itself via
-            # all_gather, so skipping the static replication check is sound
-            check_rep=False,
-        )
-        mvals, mids, stats = sharded(st.centroids_s, st.lists_s,
-                                     st.real_s, st.gids_s, self.codebook,
-                                     st.base_s, st.norms_s, member_s, q,
-                                     fbits_s, st.live_s, namespaces, tau)
+        st, placed_member = self._on_mesh(st, mesh)
+        member_s = placed_member if namespaces is not None else None
+        mvals, mids, stats = _mesh_driver(mesh, **static)(
+            st.centroids_s, st.lists_s, st.real_s, st.gids_s, self.codebook,
+            st.base_s, member_s, q, fbits_s, st.live_s, namespaces, tau)
         return SearchResult(mvals[0], mids[0], QueryStats(*(s[0] for s in stats)))

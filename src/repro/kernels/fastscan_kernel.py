@@ -35,23 +35,29 @@ Variant D — ``stream`` (gather-free probe streaming):
     The grouped variants above consume a *gathered* ``(G, cap, M//2)`` copy
     of every probed list — an O(G·cap) HBM round trip that exists only to
     feed the kernel. The stream kernels instead take ``ListStore.codes``
-    **in place** (``(nlist, cap, M//2)`` u8, memory space ANY) plus
-    scalar-prefetched probe ids, and each grid step DMAs only the probed
-    list's ``(tile_n, M//2)`` tile into VMEM — the gathered copy never
-    exists, and invalid probes (id -1) skip the DMA entirely.
+    **in place** (``(nlist, cap, M//2)`` u8) plus scalar-prefetched probe
+    ids, and each grid step's BlockSpec index map picks the probed list's
+    ``(tile_n, M//2)`` tile, which Pallas copies into VMEM — the gathered
+    copy never exists, and invalid probes (id -1) reuse the previous
+    step's block, so the pipeline issues no copy for them.
     ``fastscan_stream_topk_grouped`` additionally fuses the candidate
     reduction: instead of writing the full ``(G, cap)`` accumulation back to
     HBM it keeps a per-tile partial selection in VMEM and emits only
     ``(G, n_tiles, kc)`` (quantized dist, slot) candidate pairs — shrinking
-    the scan-stage writeback by ~cap/kc. Both stream kernels drive their
-    copies through the shared two-slot double-buffered pipeline
-    (``kernels/pipeline.py``): tile t+1 streams into one scratch buffer
-    while tile t is scanned out of the other, hiding the DMA latency on
-    real hardware.
+    the scan-stage writeback by ~cap/kc. Pallas double-buffers the block
+    copies: tile t+1 streams in while tile t is scanned.
 
-All kernels are tiled with explicit BlockSpecs. Codes arrive nibble-packed
-``(N, M//2) u8`` — one VMEM tile feeds every variant with lane-contiguous
-access (the TPU adaptation of the paper's interleaved register layout).
+All kernels are tiled with explicit BlockSpecs, laid out for Mosaic (the
+TPU kernel compiler), which lowers only blocks whose last two dims are
+(8, 128)-aligned or equal to the array's:
+
+  codes    nibble-packed ``(tile_n, M//2) u8`` tiles, split into lo/hi
+           nibble planes in registers (no interleave, no lane reshape);
+  LUTs     ``_lut_planes`` layout ``(32, M//2)`` — one row per (nibble,
+           code value), broadcast along sublanes against the code tile;
+  outputs  one row per (group, tile) of a per-group ``(1, n_tiles, X)``
+           block that stays resident while the grid walks the group's
+           tiles (``_store_row``).
 """
 from __future__ import annotations
 
@@ -62,80 +68,124 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pipeline import double_buffered_dma, double_buffered_dma_gated
 
 # Default tile sizes. Lane dim multiples of 128, sublane multiples of 8
 # (f32/i32 VREG tile is 8x128). N tile of 1024 keeps the code tile
 # (1024 x M/2 u8) well under VMEM while amortizing LUT residency.
 TILE_N = 1024
 TILE_Q = 128
+# Queries per grid step of the flat select-tree scan: its (8, tile_n) i32
+# output block is one whole sublane tile.
+ROWS_PER_STEP = 8
 
 
-def _unpack_nibbles_i32(packed_u8: jax.Array) -> jax.Array:
-    """(tn, M//2) u8 -> (tn, M) i32; lo nibble = even sub-space."""
+def _lut_planes(table_q8: jax.Array) -> jax.Array:
+    """(..., M, 16) u8 LUT -> (..., 32, M//2) u8 nibble planes.
+
+    Row ``h*16 + v``, column ``j`` holds ``T[2j + h, v]``: rows 0..15 serve
+    the low nibble of packed byte j (even sub-space 2j), rows 16..31 its
+    high nibble (odd sub-space 2j+1). One plane row broadcasts along
+    sublanes against a whole (tile_n, M//2) code tile, so no kernel ever
+    interleaves nibbles or reshapes across lanes — both of which Mosaic
+    refuses. ``planes.reshape(..., 16*M)`` is the flat LUT row the one-hot
+    MXU kernels contract against (``_onehot_codes`` lane order).
+    """
+    *lead, m, k = table_q8.shape
+    t = table_q8.reshape(*lead, m // 2, 2, k)
+    return jnp.moveaxis(t, -3, -1).reshape(*lead, 2 * k, m // 2)
+
+
+def _nibbles(packed_u8: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(tn, M//2) u8 -> (lo, hi) (tn, M//2) i32: codes of sub-spaces 2j, 2j+1."""
     p = packed_u8.astype(jnp.int32)
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    # interleave: out[:, 0::2] = lo, out[:, 1::2] = hi, without scatter
-    # (tn, mh) -> (tn, mh, 2) -> (tn, m)
-    return jnp.stack([lo, hi], axis=-1).reshape(p.shape[0], -1)
+    return p & 0xF, (p >> 4) & 0xF
+
+
+def _store_row(ref, row_idx, row: jax.Array) -> None:
+    """Write one (1, X) row into a per-group (1, R, X) output block.
+
+    Per-group outputs are shaped (G, R, X) with block (1, R, X): the block's
+    last two dims equal the array's, which Mosaic accepts for any R and X,
+    and the block stays resident while the grid walks the group's R tiles.
+    """
+    ref[0, pl.ds(row_idx, 1), :] = row
+
+
+def _first_argmin(a: jax.Array, mn: jax.Array) -> jax.Array:
+    """Lowest lane index holding the row minimum ``mn``: (r, n) -> (r, 1).
+
+    Spelled as a masked min because Mosaic lowers ``argmin`` for f32 only;
+    the first-occurrence tie-break is the one ``masked_topk`` uses.
+    """
+    iota = jax.lax.broadcasted_iota(jnp.int32, a.shape, a.ndim - 1)
+    return jnp.min(jnp.where(a == mn, iota, a.shape[-1]), axis=-1,
+                   keepdims=True)
 
 
 # ---------------------------------------------------------------------------
 # Variant A: select-tree (VPU)
 # ---------------------------------------------------------------------------
 
-def _select_tree_acc(t: jax.Array, codes: jax.Array) -> jax.Array:
-    """Select-tree ADC accumulation: t (M, 16) i32 LUT x codes (tn, M) i32
-    -> (tn,) i32 sums.
+def _select16(planes: jax.Array, base: int, codes: jax.Array) -> jax.Array:
+    """out[n, j] = planes[base + codes[n, j], j] by a 4-level select tree.
 
-    A 16-way LUT lookup decomposed into log2(16) = 4 levels of 2-way vector
-    selects (the paper's 256-bit shuffle via 2x128-bit shuffles, one level
-    deeper on TPU)."""
-    b0 = (codes & 1).astype(jnp.bool_)
-    b1 = (codes & 2).astype(jnp.bool_)
-    b2 = (codes & 4).astype(jnp.bool_)
-    b3 = (codes & 8).astype(jnp.bool_)
+    planes (32, M//2) i32, codes (tn, M//2) i32 in [0, 16). A 16-way LUT
+    lookup decomposed into log2(16) = 4 levels of 2-way vector selects,
+    most significant bit first (the paper's 256-bit shuffle via 2x128-bit
+    shuffles, one level deeper on TPU).
+    """
+    rows = [planes[base + v: base + v + 1, :] for v in range(16)]
+    for bit in (8, 4, 2, 1):
+        half = len(rows) // 2
+        take_hi = (codes & bit) != 0
+        rows = [jnp.where(take_hi, rows[half + i], rows[i])
+                for i in range(half)]
+    return rows[0]
 
-    lo8 = t[None, :, 0:8]   # (1, M, 8) broadcast over the N tile
-    hi8 = t[None, :, 8:16]
-    s3 = jnp.where(b3[:, :, None], hi8, lo8)          # (tn, M, 8)
-    s2 = jnp.where(b2[:, :, None], s3[..., 4:8], s3[..., 0:4])  # (tn, M, 4)
-    s1 = jnp.where(b1[:, :, None], s2[..., 2:4], s2[..., 0:2])  # (tn, M, 2)
-    s0 = jnp.where(b0, s1[..., 1], s1[..., 0])        # (tn, M)
-    return jnp.sum(s0, axis=-1, dtype=jnp.int32)
+
+def _select_tree_acc(planes: jax.Array, lo: jax.Array, hi: jax.Array
+                     ) -> jax.Array:
+    """Select-tree ADC: (32, M//2) i32 planes x nibbles (tn, M//2) -> (1, tn)
+    i32 sums. The per-code sums land along sublanes; one transpose turns
+    them into the lane-dense row every output block stores."""
+    per = _select16(planes, 0, lo) + _select16(planes, 16, hi)
+    return jnp.sum(per, axis=1, keepdims=True).T
 
 
 def _select_tree_kernel(table_ref, codes_ref, out_ref):
-    """One query row x one N tile.
+    """ROWS_PER_STEP queries x one N tile.
 
-    table_ref: (1, M, 16) u8 block  — the register-resident LUT
-    codes_ref: (tn, M//2) u8 block  — nibble-packed codes
-    out_ref:   (1, tn) i32 block
+    table_ref: (R, 32, M//2) u8 block — R queries' LUT planes
+    codes_ref: (tn, M//2) u8 block  — nibble-packed codes, split once
+    out_ref:   (R, tn) i32 block
     """
-    codes = _unpack_nibbles_i32(codes_ref[...])  # (tn, M)
-    t = table_ref[0].astype(jnp.int32)  # (M, 16)
-    out_ref[...] = _select_tree_acc(t, codes)[None, :]
+    lo, hi = _nibbles(codes_ref[...])
+    for r in range(out_ref.shape[0]):
+        out_ref[r:r + 1, :] = _select_tree_acc(
+            table_ref[r].astype(jnp.int32), lo, hi)
 
 
 def fastscan_select_tree(table_q8: jax.Array, packed_codes: jax.Array, *,
-                         tile_n: int = TILE_N, interpret: bool = True) -> jax.Array:
-    """(Q, M, 16) u8 x (N, M//2) u8 -> (Q, N) i32. Q and N pre-padded."""
+                         tile_n: int = TILE_N, interpret: bool) -> jax.Array:
+    """(Q, M, 16) u8 x (N, M//2) u8 -> (Q, N) i32. Q pre-padded to a
+    ROWS_PER_STEP multiple, N to a tile_n multiple."""
     q, m, k = table_q8.shape
     n, mh = packed_codes.shape
     assert k == 16 and mh * 2 == m and n % tile_n == 0
-    grid = (q, n // tile_n)
+    assert q % ROWS_PER_STEP == 0, (q, ROWS_PER_STEP)
+    grid = (q // ROWS_PER_STEP, n // tile_n)
     return pl.pallas_call(
         _select_tree_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, m, 16), lambda qi, ni: (qi, 0, 0)),
+            pl.BlockSpec((ROWS_PER_STEP, 2 * k, mh),
+                         lambda qi, ni: (qi, 0, 0)),
             pl.BlockSpec((tile_n, mh), lambda qi, ni: (ni, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda qi, ni: (qi, ni)),
+        out_specs=pl.BlockSpec((ROWS_PER_STEP, tile_n), lambda qi, ni: (qi, ni)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.int32),
         interpret=interpret,
-    )(table_q8, packed_codes)
+    )(_lut_planes(table_q8), packed_codes)
 
 
 def _select_tree_grouped_kernel(table_ref, codes_ref, out_ref):
@@ -143,16 +193,16 @@ def _select_tree_grouped_kernel(table_ref, codes_ref, out_ref):
     *and* its own code tile (gathered IVF lists), unlike the shared-database
     variant above.
 
-    table_ref: (1, M, 16) u8 block; codes_ref: (1, tn, M//2) u8 block;
-    out_ref: (1, tn) i32 block.
+    table_ref: (1, 32, M//2) u8 planes; codes_ref: (1, tn, M//2) u8 block;
+    out_ref: (1, n_tiles, tn) i32, this group's row ``ni`` written.
     """
-    codes = _unpack_nibbles_i32(codes_ref[0])  # (tn, M)
-    t = table_ref[0].astype(jnp.int32)  # (M, 16)
-    out_ref[...] = _select_tree_acc(t, codes)[None, :]
+    lo, hi = _nibbles(codes_ref[0])
+    _store_row(out_ref, pl.program_id(1),
+               _select_tree_acc(table_ref[0].astype(jnp.int32), lo, hi))
 
 
-def fastscan_select_tree_grouped(table_q8: jax.Array, packed_codes: jax.Array, *,
-                                 tile_n: int = TILE_N, interpret: bool = True
+def fastscan_select_tree_grouped(table_q8: jax.Array, packed_codes: jax.Array,
+                                 *, tile_n: int = TILE_N, interpret: bool
                                  ) -> jax.Array:
     """Grouped ADC: (G, M, 16) u8 x (G, N, M//2) u8 -> (G, N) i32.
 
@@ -163,50 +213,71 @@ def fastscan_select_tree_grouped(table_q8: jax.Array, packed_codes: jax.Array, *
     g, m, k = table_q8.shape
     gc, n, mh = packed_codes.shape
     assert k == 16 and mh * 2 == m and gc == g and n % tile_n == 0
-    grid = (g, n // tile_n)
+    n_tiles = n // tile_n
     return pl.pallas_call(
         _select_tree_grouped_kernel,
-        grid=grid,
+        grid=(g, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, m, 16), lambda gi, ni: (gi, 0, 0)),
+            pl.BlockSpec((1, 2 * k, mh), lambda gi, ni: (gi, 0, 0)),
             pl.BlockSpec((1, tile_n, mh), lambda gi, ni: (gi, ni, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda gi, ni: (gi, ni)),
-        out_shape=jax.ShapeDtypeStruct((g, n), jnp.int32),
+        out_specs=pl.BlockSpec((1, n_tiles, tile_n), lambda gi, ni: (gi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, n_tiles, tile_n), jnp.int32),
         interpret=interpret,
-    )(table_q8, packed_codes)
+    )(_lut_planes(table_q8), packed_codes).reshape(g, n)
 
 
 # ---------------------------------------------------------------------------
 # Variant B: one-hot MXU
 # ---------------------------------------------------------------------------
 
-def _onehot_mxu_kernel(table_ref, codes_ref, out_ref):
-    """table_ref: (tq, M*16) u8; codes_ref: (tn, M//2) u8; out_ref: (tq, tn) i32."""
-    codes = _unpack_nibbles_i32(codes_ref[...])  # (tn, M)
-    tn, m = codes.shape
-    # one-hot on the VPU: (tn, M, 16) -> (tn, M*16), bf16 so the MXU eats it
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tn, m, 16), dimension=2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.bfloat16).reshape(tn, m * 16)
-    t = table_ref[...].astype(jnp.bfloat16)  # (tq, M*16)
-    # (tq, M16) x (M16, tn) -> (tq, tn) on the MXU, f32 accumulation (exact)
+def _onehot_codes(packed_u8: jax.Array) -> jax.Array:
+    """(tn, M//2) u8 -> (tn, 16*M) bf16 one-hot planes.
+
+    Lane ``h*8M + v*(M//2) + j`` is 1 iff nibble h of byte j equals v — the
+    lane order of ``_lut_planes(...).reshape(16*M)``. Built by repeating the
+    nibble columns along lanes and comparing against a lane iota, so the
+    (tn, M, 16) -> (tn, M*16) reshape Mosaic refuses never happens.
+    """
+    lo, hi = _nibbles(packed_u8)
+    rep = jnp.concatenate([lo] * 16 + [hi] * 16, axis=1)
+    v = (jax.lax.broadcasted_iota(jnp.int32, rep.shape, 1) // lo.shape[1]) % 16
+    return (rep == v).astype(jnp.bfloat16)
+
+
+def _mxu_acc(tflat: jax.Array, packed_u8: jax.Array) -> jax.Array:
+    """(r, 16*M) bf16 flat LUT rows x (tn, M//2) u8 codes -> (r, tn) i32.
+
+    Exact: u8 LUT entries and one-hot 0/1 are exact in bf16, and the f32
+    accumulation of M nonzero terms (<= 255*M) is exact.
+    """
     acc = jax.lax.dot_general(
-        t, onehot,
+        tflat, _onehot_codes(packed_u8),
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    out_ref[...] = acc.astype(jnp.int32)
+        preferred_element_type=jnp.float32)
+    return acc.astype(jnp.int32)
+
+
+def _mxu_table(table_q8: jax.Array) -> jax.Array:
+    """(..., M, 16) u8 -> (..., 16*M) bf16 flat LUT rows (u8 -> bf16 is a
+    cast Mosaic lacks, so it happens here, outside the kernel)."""
+    planes = _lut_planes(table_q8)
+    return planes.reshape(*planes.shape[:-2], -1).astype(jnp.bfloat16)
+
+
+def _onehot_mxu_kernel(table_ref, codes_ref, out_ref):
+    """table_ref: (tq, 16*M) bf16; codes_ref: (tn, M//2) u8; out_ref: (tq, tn) i32."""
+    out_ref[...] = _mxu_acc(table_ref[...], codes_ref[...])
 
 
 def fastscan_onehot_mxu(table_q8: jax.Array, packed_codes: jax.Array, *,
                         tile_n: int = TILE_N, tile_q: int = TILE_Q,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """(Q, M, 16) u8 x (N, M//2) u8 -> (Q, N) i32. Q, N pre-padded to tiles."""
     q, m, k = table_q8.shape
     n, mh = packed_codes.shape
     assert k == 16 and mh * 2 == m
     assert q % tile_q == 0 and n % tile_n == 0, (q, tile_q, n, tile_n)
-    t_flat = table_q8.reshape(q, m * 16)
     grid = (q // tile_q, n // tile_n)
     return pl.pallas_call(
         _onehot_mxu_kernel,
@@ -218,37 +289,26 @@ def fastscan_onehot_mxu(table_q8: jax.Array, packed_codes: jax.Array, *,
         out_specs=pl.BlockSpec((tile_q, tile_n), lambda qi, ni: (qi, ni)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.int32),
         interpret=interpret,
-    )(t_flat, packed_codes)
+    )(_mxu_table(table_q8), packed_codes)
 
 
 def _onehot_mxu_grouped_kernel(table_ref, codes_ref, out_ref):
     """One (query, probe) group x one cap tile, on the MXU.
 
-    table_ref: (1, M*16) u8 block — this group's flattened LUT
+    table_ref: (1, 1, 16*M) bf16 — this group's flat LUT row
     codes_ref: (1, tn, M//2) u8 block — this group's gathered code tile
-    out_ref:   (1, tn) i32 block
+    out_ref:   (1, n_tiles, tn) i32, this group's row ``ni`` written
 
-    The grouped ADC gather is a per-group matvec: unpack the nibble codes to
-    one-hot (tn, M, 16) planes, flatten to (tn, M*16) bf16, and contract
-    against the group's own (1, M*16) LUT row on the MXU with f32
-    accumulation. Exactness argument is identical to the flat variant above
-    (u8 and 0/1 exact in bf16; <= M*16 f32 summands exact).
+    The grouped ADC gather is a per-group matvec: expand the nibble codes
+    to one-hot lanes and contract against the group's own LUT row on the
+    MXU with f32 accumulation (exactness as in ``_mxu_acc``).
     """
-    codes = _unpack_nibbles_i32(codes_ref[0])  # (tn, M)
-    tn, m = codes.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tn, m, 16), dimension=2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.bfloat16).reshape(tn, m * 16)
-    t = table_ref[...].astype(jnp.bfloat16)  # (1, M*16)
-    acc = jax.lax.dot_general(
-        t, onehot,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, tn)
-    out_ref[...] = acc.astype(jnp.int32)
+    _store_row(out_ref, pl.program_id(1),
+               _mxu_acc(table_ref[0], codes_ref[0]))
 
 
-def fastscan_onehot_mxu_grouped(table_q8: jax.Array, packed_codes: jax.Array, *,
-                                tile_n: int = TILE_N, interpret: bool = True
+def fastscan_onehot_mxu_grouped(table_q8: jax.Array, packed_codes: jax.Array,
+                                *, tile_n: int = TILE_N, interpret: bool
                                 ) -> jax.Array:
     """Grouped one-hot MXU ADC: (G, M, 16) u8 x (G, cap, M//2) u8 -> (G, cap) i32.
 
@@ -262,19 +322,18 @@ def fastscan_onehot_mxu_grouped(table_q8: jax.Array, packed_codes: jax.Array, *,
     g, m, k = table_q8.shape
     gc, n, mh = packed_codes.shape
     assert k == 16 and mh * 2 == m and gc == g and n % tile_n == 0
-    t_flat = table_q8.reshape(g, m * 16)
-    grid = (g, n // tile_n)
+    n_tiles = n // tile_n
     return pl.pallas_call(
         _onehot_mxu_grouped_kernel,
-        grid=grid,
+        grid=(g, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, m * 16), lambda gi, ni: (gi, 0)),
+            pl.BlockSpec((1, 1, m * 16), lambda gi, ni: (gi, 0, 0)),
             pl.BlockSpec((1, tile_n, mh), lambda gi, ni: (gi, ni, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda gi, ni: (gi, ni)),
-        out_shape=jax.ShapeDtypeStruct((g, n), jnp.int32),
+        out_specs=pl.BlockSpec((1, n_tiles, tile_n), lambda gi, ni: (gi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, n_tiles, tile_n), jnp.int32),
         interpret=interpret,
-    )(t_flat, packed_codes)
+    )(_mxu_table(table_q8)[:, None, :], packed_codes).reshape(g, n)
 
 
 # ---------------------------------------------------------------------------
@@ -282,53 +341,47 @@ def fastscan_onehot_mxu_grouped(table_q8: jax.Array, packed_codes: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 def _blockmin_kernel(table_ref, codes_ref, min_ref, arg_ref, *, tile_n: int):
-    codes = _unpack_nibbles_i32(codes_ref[...])  # (tn, M)
-    tn, m = codes.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tn, m, 16), dimension=2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.bfloat16).reshape(tn, m * 16)
-    t = table_ref[...].astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        t, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(jnp.int32)  # (tq, tn)
-    ni = pl.program_id(1)
+    """table_ref (tq, 16*M) bf16; codes_ref (tn, M//2) u8; min_ref/arg_ref
+    (1, tq, 1) i32 — this N tile's column of the (n_tiles, Q, 1) outputs."""
+    acc = _mxu_acc(table_ref[...], codes_ref[...])  # (tq, tn)
     # in-register reduction: the movemask/top-k filter analogue
-    min_ref[...] = jnp.min(acc, axis=-1, keepdims=True)
-    local_arg = jnp.argmin(acc, axis=-1).astype(jnp.int32)
-    arg_ref[...] = (local_arg + ni * tile_n)[:, None]
+    mn = jnp.min(acc, axis=-1, keepdims=True)
+    min_ref[0] = mn
+    arg_ref[0] = _first_argmin(acc, mn) + pl.program_id(1) * tile_n
 
 
 def fastscan_blockmin(table_q8: jax.Array, packed_codes: jax.Array, *,
                       tile_n: int = TILE_N, tile_q: int = TILE_Q,
-                      interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                      interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused ADC + per-N-tile min: (Q, N/tile_n) i32 mins and global argmin ids."""
     q, m, k = table_q8.shape
     n, mh = packed_codes.shape
     assert k == 16 and mh * 2 == m
     assert q % tile_q == 0 and n % tile_n == 0
-    t_flat = table_q8.reshape(q, m * 16)
-    grid = (q // tile_q, n // tile_n)
+    nb = n // tile_n
     kernel = functools.partial(_blockmin_kernel, tile_n=tile_n)
-    return pl.pallas_call(
+    mins, args = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(q // tile_q, nb),
         in_specs=[
             pl.BlockSpec((tile_q, m * 16), lambda qi, ni: (qi, 0)),
             pl.BlockSpec((tile_n, mh), lambda qi, ni: (ni, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_q, 1), lambda qi, ni: (qi, ni)),
-            pl.BlockSpec((tile_q, 1), lambda qi, ni: (qi, ni)),
+            pl.BlockSpec((1, tile_q, 1), lambda qi, ni: (ni, qi, 0)),
+            pl.BlockSpec((1, tile_q, 1), lambda qi, ni: (ni, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, n // tile_n), jnp.int32),
-            jax.ShapeDtypeStruct((q, n // tile_n), jnp.int32),
+            jax.ShapeDtypeStruct((nb, q, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nb, q, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(t_flat, packed_codes)
+    )(_mxu_table(table_q8), packed_codes)
+    return mins[..., 0].T, args[..., 0].T
 
 
 # ---------------------------------------------------------------------------
-# Variant D: gather-free probe streaming (in-kernel list DMA)
+# Variant D: gather-free probe streaming (in-place list tiles)
 # ---------------------------------------------------------------------------
 
 # Larger than any reachable ADC sum (<= 128 sub-spaces * 255 = 32640), used
@@ -336,84 +389,78 @@ def fastscan_blockmin(table_q8: jax.Array, packed_codes: jax.Array, *,
 ACC_SENTINEL = jnp.iinfo(jnp.int32).max
 
 
-def _stream_dma_plan(probe_ref, codes_hbm, scratch, sem, *,
-                     tile_n: int, n_tiles: int, total: int):
-    """The (make_dma, valid) pair shared by both stream scan kernels.
+def _fetch_plan(probe_ids: jax.Array, n_tiles: int) -> jax.Array:
+    """(G,) probe ids -> (G*n_tiles,) i32 source step per sequential step.
 
-    A global sequential step ``s`` (grid order is group-major) maps to group
-    ``s // n_tiles``, cap tile ``s % n_tiles``; its transfer is one
-    ``(tile_n, M//2)`` slice of the probed list, landed in scratch slot
-    ``s % 2`` with semaphore ``s % 2`` — the two-buffer pipeline's rotation.
-    ``valid`` clamps ``s`` (the pipeline probes one step past the end).
+    Grid steps run group-major: step ``s`` is group ``s // n_tiles``, cap
+    tile ``s % n_tiles``. A step whose probe is invalid (-1) borrows the
+    block of the nearest earlier valid step (or, before the first valid
+    step, of that first one), so its code block index equals its
+    neighbour's and the block pipeline issues no copy for it: invalid
+    probes cost no HBM traffic.
     """
-    def start(s, slot):
-        lid = probe_ref[s // n_tiles]
-        pltpu.make_async_copy(
-            codes_hbm.at[lid, pl.ds((s % n_tiles) * tile_n, tile_n), :],
-            scratch.at[slot], sem.at[slot]).start()
-
-    def wait(s, slot):
-        lid = probe_ref[s // n_tiles]
-        pltpu.make_async_copy(
-            codes_hbm.at[lid, pl.ds((s % n_tiles) * tile_n, tile_n), :],
-            scratch.at[slot], sem.at[slot]).wait()
-
-    def valid(s):
-        return probe_ref[jnp.minimum(s, total - 1) // n_tiles] >= 0
-
-    return start, wait, valid
+    steps = jnp.arange(probe_ids.shape[0] * n_tiles, dtype=jnp.int32)
+    valid = jnp.repeat(probe_ids >= 0, n_tiles)
+    last = jax.lax.cummax(jnp.where(valid, steps, -1))
+    first = jnp.argmax(valid).astype(jnp.int32)
+    return jnp.where(last >= 0, last, first)
 
 
-def _stream_grouped_kernel(probe_ref, table_ref, codes_hbm, out_ref,
-                           scratch, sem, *, tile_n: int, n_tiles: int,
-                           g: int):
-    """One (query, probe) group x one cap tile, codes DMA'd from HBM in place.
+def _codes_spec(tile_n: int, n_tiles: int, mh: int) -> pl.BlockSpec:
+    """The in-place code tile of a step, fetched by the block pipeline.
+
+    The index map reads the scalar-prefetched probe ids (first prefetch
+    operand) and fetch plan (last prefetch operand): step (gi, ni) lands
+    tile ``src % n_tiles`` of list ``probe[src // n_tiles]`` with ``src``
+    from ``_fetch_plan``. Pallas double-buffers the copies (tile t+1
+    streams in while tile t is scanned) and skips a copy whose block index
+    repeats the previous step's. Manual DMAs of this (tile_n, M//2) slice
+    are refused by Mosaic (the M//2 lane dim is not 128-aligned); the
+    block pipeline takes it.
+    """
+    def index(gi, ni, probe_ref, *rest):
+        src = rest[-1][gi * n_tiles + ni]
+        return (jnp.maximum(probe_ref[src // n_tiles], 0), src % n_tiles, 0)
+    return pl.BlockSpec((1, tile_n, mh), index)
+
+
+def _stream_grouped_kernel(probe_ref, src_ref, table_ref, codes_ref,
+                           out_ref, *, tile_n: int):
+    """One (query, probe) group x one cap tile, codes fetched in place.
 
     probe_ref: (G,) i32 scalar-prefetched flat probe ids (-1 = no probe)
-    table_ref: (1, M, 16) u8 block — this group's LUT (VMEM)
-    codes_hbm: (nlist, cap, M//2) u8, memory space ANY — the ListStore,
-               untouched; only the probed tile ever crosses into VMEM
-    out_ref:   (1, tile_n) i32 block
-    scratch:   (2, tile_n, M//2) u8 VMEM — double-buffered DMA landing pads
-    sem:       (2,) DMA semaphores, one per scratch slot
-
-    Grid steps run group-major and sequentially; ``double_buffered_dma``
-    keeps tile t+1's copy in flight (possibly for the *next* group) while
-    tile t is scanned, hiding the HBM latency the one-DMA-per-step version
-    exposed.
+    src_ref:   (G*n_tiles,) i32 scalar-prefetched fetch plan (index maps)
+    table_ref: (1, 32, M//2) u8 block — this group's LUT planes (VMEM)
+    codes_ref: (1, tile_n, M//2) u8 block — the probed list's tile, copied
+               out of the untouched ListStore by the block pipeline
+    out_ref:   (1, n_tiles, tile_n) i32 — this group's row ``ni`` written
     """
-    gi = pl.program_id(0)
+    del src_ref  # read by the index maps only
     ni = pl.program_id(1)
-    step = gi * n_tiles + ni
-    lid = probe_ref[gi]
-
-    start, wait, valid = _stream_dma_plan(
-        probe_ref, codes_hbm, scratch, sem,
-        tile_n=tile_n, n_tiles=n_tiles, total=g * n_tiles)
-    double_buffered_dma(step, g * n_tiles, start, wait, valid)
+    lid = probe_ref[pl.program_id(0)]
 
     @pl.when(lid >= 0)
     def _scan():
-        codes = _unpack_nibbles_i32(scratch[step % 2])  # (tn, M)
-        t = table_ref[0].astype(jnp.int32)              # (M, 16)
-        out_ref[...] = _select_tree_acc(t, codes)[None, :]
+        lo, hi = _nibbles(codes_ref[0])
+        _store_row(out_ref, ni, _select_tree_acc(
+            table_ref[0].astype(jnp.int32), lo, hi))
 
     @pl.when(lid < 0)
-    def _skip():  # no DMA, no scan: invalid probes cost nothing
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def _skip():  # no copy, no scan: invalid probes cost nothing
+        _store_row(out_ref, ni, jnp.zeros((1, tile_n), jnp.int32))
 
 
 def fastscan_stream_grouped(table_q8: jax.Array, list_codes: jax.Array,
                             probe_ids: jax.Array, *, tile_n: int = TILE_N,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool) -> jax.Array:
     """Gather-free grouped ADC: (G, M, 16) u8 LUTs x (nlist, cap, M//2) u8
     codes *in place* + (G,) i32 probe ids -> (G, cap) i32.
 
     Semantically ``fastscan_select_tree_grouped(table, codes[probe_ids])``
     without the gathered copy ever existing: a PrefetchScalarGridSpec makes
     ``probe_ids`` available before the grid runs, and each (group, cap-tile)
-    step DMAs only that probed list's tile from HBM into a VMEM scratch.
-    Invalid probes (id -1) skip the DMA entirely and emit zeros (their
+    step copies only that probed list's tile from HBM into VMEM. Invalid
+    probes (id -1) issue no copy and emit zeros (their
     output is id-masked downstream, like gathered padding). cap must be a
     ``tile_n`` multiple — the store is scanned in place, never padded.
     """
@@ -422,27 +469,24 @@ def fastscan_stream_grouped(table_q8: jax.Array, list_codes: jax.Array,
     assert k == 16 and mh * 2 == m and probe_ids.shape == (g,)
     assert cap % tile_n == 0, (cap, tile_n)
     n_tiles = cap // tile_n
+    probes = probe_ids.astype(jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(g, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, m, 16), lambda gi, ni, pr: (gi, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, 2 * k, mh), lambda gi, ni, *_: (gi, 0, 0)),
+            _codes_spec(tile_n, n_tiles, mh),
         ],
-        out_specs=pl.BlockSpec((1, tile_n), lambda gi, ni, pr: (gi, ni)),
-        scratch_shapes=[
-            pltpu.VMEM((2, tile_n, mh), jnp.uint8),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        out_specs=pl.BlockSpec((1, n_tiles, tile_n),
+                               lambda gi, ni, *_: (gi, 0, 0)),
     )
-    kernel = functools.partial(_stream_grouped_kernel, tile_n=tile_n,
-                               n_tiles=n_tiles, g=g)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_stream_grouped_kernel, tile_n=tile_n),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, cap), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((g, n_tiles, tile_n), jnp.int32),
         interpret=interpret,
-    )(probe_ids, table_q8, list_codes)
+    )(probes, _fetch_plan(probes, n_tiles), _lut_planes(table_q8),
+      list_codes).reshape(g, cap)
 
 
 def _tile_topk(acc: jax.Array, slot_base: jax.Array, kc: int
@@ -452,8 +496,8 @@ def _tile_topk(acc: jax.Array, slot_base: jax.Array, kc: int
     Entries equal to ACC_SENTINEL are treated as absent. Returns
     (vals (1, kc) i32 ascending, slots (1, kc) i32 global slot ids, -1 where
     fewer than kc real entries exist). Ties resolve to the lowest slot
-    (argmin takes the first occurrence), matching ``masked_topk``'s
-    lowest-flat-index tie-break on the full array.
+    (first occurrence), matching ``masked_topk``'s lowest-flat-index
+    tie-break on the full array.
     """
     tn = acc.shape[-1]
     iota_n = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
@@ -462,7 +506,7 @@ def _tile_topk(acc: jax.Array, slot_base: jax.Array, kc: int
     def body(j, carry):
         a, vals, slots = carry
         mn = jnp.min(a, axis=-1, keepdims=True)                    # (1, 1)
-        am = jnp.argmin(a, axis=-1).astype(jnp.int32)[:, None]     # (1, 1)
+        am = _first_argmin(a, mn)                                  # (1, 1)
         vals = jnp.where(iota_k == j, mn, vals)
         slots = jnp.where(iota_k == j, am, slots)
         a = jnp.where(iota_n == am, ACC_SENTINEL, a)
@@ -476,68 +520,81 @@ def _tile_topk(acc: jax.Array, slot_base: jax.Array, kc: int
     return vals, slots
 
 
-def _stream_topk_kernel(probe_ref, sizes_ref, table_ref, *rest,
-                        tile_n: int, kc: int, n_tiles: int, g: int,
-                        has_filter: bool):
+def _tile_filter_bits(words_u8: jax.Array, tile_n: int) -> jax.Array:
+    """(1, W) u8 packed filter words -> (1, tile_n) i32 bits, LSB-first.
+
+    Bit j of word w is slot w*8 + j (core/lists.py layout). Each word is
+    repeated over its 8 lanes by a 0/1 matmul — exact, since bytes are
+    bf16-exact — then shifted by its lane's bit index: the lane-dense
+    unpack Mosaic accepts in place of a stack + reshape.
+    """
+    w = words_u8.astype(jnp.int32)
+    nw = w.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (nw, 8 * nw), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (nw, 8 * nw), 1)
+    spread = (cols // 8 == rows).astype(jnp.bfloat16)
+    rep = jax.lax.dot_general(
+        w.astype(jnp.float32).astype(jnp.bfloat16), spread,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, rep.shape, 1)
+    return ((rep >> (lane % 8)) & 1)[:, :tile_n]
+
+
+def _masked_tile_acc(table_ref, codes_ref, sizes_ref, lid, fbits_ref, *,
+                     tile_n: int, ni):
+    """Scan the landed tile and mask non-candidates to ACC_SENTINEL: slots
+    past the list's occupancy and, with a filter, rows whose bit is 0 — a
+    filtered row is indistinguishable from padding before selection."""
+    lo, hi = _nibbles(codes_ref[0])
+    acc = _select_tree_acc(table_ref[0].astype(jnp.int32), lo, hi)  # (1, tn)
+    slot_ids = (jax.lax.broadcasted_iota(jnp.int32, (1, tile_n), 1)
+                + ni * tile_n)
+    acc = jnp.where(slot_ids < sizes_ref[lid], acc, ACC_SENTINEL)
+    if fbits_ref is not None:
+        bits = _tile_filter_bits(fbits_ref[0], tile_n)
+        acc = jnp.where(bits > 0, acc, ACC_SENTINEL)
+    return acc
+
+
+def _stream_topk_kernel(probe_ref, sizes_ref, src_ref, table_ref, *rest,
+                        tile_n: int, kc: int, has_filter: bool):
     """Stream kernel + fused per-tile candidate selection (+ optional
     per-row predicate mask).
 
     Outputs per (group, cap-tile): the kc smallest quantized dists and their
-    global slot ids within the list (-1 = absent). Slots past the list's
-    true occupancy (``sizes_ref``) are masked to ACC_SENTINEL *before* the
-    selection, so padding can never displace a real candidate. With
-    ``has_filter`` the group's packed filter-bitmap row (``fbits_ref``,
-    (1, W) u8, LSB-first — see core/lists.py) rides into VMEM next to the
-    LUT; the tile's bits are unpacked in registers and rows whose bit is 0
-    are masked to ACC_SENTINEL through the *same* pre-selection path as the
-    occupancy mask — a filtered row is indistinguishable from padding, so
-    the fused selection stays bit-identical to a post-filtered oracle. Same
-    double-buffered DMA pipeline as ``_stream_grouped_kernel``: tile t+1's
-    copy overlaps tile t's scan+selection.
+    global slot ids within the list (-1 = absent), as row ``ni`` of the
+    group's (1, n_tiles, kc) blocks. Slots past the list's true occupancy
+    (``sizes_ref``) are masked to ACC_SENTINEL *before* the selection, so
+    padding can never displace a real candidate. With ``has_filter`` this
+    tile's packed filter words (``fbits_ref``, (1, 1, W_t) u8, LSB-first —
+    see core/lists.py) ride into VMEM next to the LUT; rows whose bit is 0
+    are masked through the *same* pre-selection path as the occupancy mask,
+    so the fused selection stays bit-identical to a post-filtered oracle.
+    Codes arrive as in ``_stream_grouped_kernel``: tile t+1's copy overlaps
+    tile t's scan+selection.
     """
+    del src_ref  # read by the index maps only
     if has_filter:
-        fbits_ref, codes_hbm, vals_ref, slots_ref, scratch, sem = rest
+        fbits_ref, codes_ref, vals_ref, slots_ref = rest
     else:
-        codes_hbm, vals_ref, slots_ref, scratch, sem = rest
+        codes_ref, vals_ref, slots_ref = rest
         fbits_ref = None
-    gi = pl.program_id(0)
     ni = pl.program_id(1)
-    step = gi * n_tiles + ni
-    lid = probe_ref[gi]
-
-    start, wait, valid = _stream_dma_plan(
-        probe_ref, codes_hbm, scratch, sem,
-        tile_n=tile_n, n_tiles=n_tiles, total=g * n_tiles)
-    double_buffered_dma(step, g * n_tiles, start, wait, valid)
+    lid = probe_ref[pl.program_id(0)]
 
     @pl.when(lid >= 0)
     def _scan():
-        codes = _unpack_nibbles_i32(scratch[step % 2])  # (tn, M)
-        t = table_ref[0].astype(jnp.int32)
-        acc = _select_tree_acc(t, codes)[None, :]  # (1, tn)
-        slot = (jax.lax.broadcasted_iota(jnp.int32, (1, tile_n), 1)
-                + ni * tile_n)
-        acc = jnp.where(slot < sizes_ref[lid], acc, ACC_SENTINEL)
-        if fbits_ref is not None:
-            # unpack this group's bitmap row (1, W) -> (1, W*8) bits with
-            # the same stack+reshape idiom as the nibble unpack (LSB-first
-            # bit j of word w = slot w*8 + j), then slice this tile's span.
-            # W*8 >= cap >= (ni+1)*tile_n, so the slice never runs off the
-            # end; excluded rows join the occupancy padding at ACC_SENTINEL.
-            fb = fbits_ref[...].astype(jnp.int32)  # (1, W)
-            bits = jnp.stack([(fb >> j) & 1 for j in range(8)],
-                             axis=-1).reshape(1, -1)
-            tile_bits = jax.lax.dynamic_slice(
-                bits, (0, ni * tile_n), (1, tile_n))
-            acc = jnp.where(tile_bits > 0, acc, ACC_SENTINEL)
+        acc = _masked_tile_acc(table_ref, codes_ref, sizes_ref, lid,
+                               fbits_ref, tile_n=tile_n, ni=ni)
         vals, slots = _tile_topk(acc, ni * tile_n, kc)
-        vals_ref[...] = vals[:, None, :]
-        slots_ref[...] = slots[:, None, :]
+        _store_row(vals_ref, ni, vals)
+        _store_row(slots_ref, ni, slots)
 
     @pl.when(lid < 0)
     def _skip():
-        vals_ref[...] = jnp.full_like(vals_ref, ACC_SENTINEL)
-        slots_ref[...] = jnp.full_like(slots_ref, -1)
+        _store_row(vals_ref, ni, jnp.full((1, kc), ACC_SENTINEL, jnp.int32))
+        _store_row(slots_ref, ni, jnp.full((1, kc), -1, jnp.int32))
 
 
 def _merge_smallest(cat: jax.Array, kc: int) -> jax.Array:
@@ -551,7 +608,7 @@ def _merge_smallest(cat: jax.Array, kc: int) -> jax.Array:
     def body(j, carry):
         a, vals = carry
         mn = jnp.min(a, axis=-1, keepdims=True)
-        am = jnp.argmin(a, axis=-1).astype(jnp.int32)[:, None]
+        am = _first_argmin(a, mn)
         vals = jnp.where(iota_k == j, mn, vals)
         a = jnp.where(iota_n == am, jnp.float32(jnp.inf), a)
         return a, vals
@@ -561,9 +618,9 @@ def _merge_smallest(cat: jax.Array, kc: int) -> jax.Array:
     return vals
 
 
-def _stream_topk_prune_kernel(probe_ref, sizes_ref, table_ref, bounds_ref,
-                              scales_ref, biases_ref, *rest, tile_n: int,
-                              kc: int, n_tiles: int, g: int, gpq: int,
+def _stream_topk_prune_kernel(probe_ref, sizes_ref, src_ref, table_ref,
+                              bounds_ref, scales_ref, biases_ref, *rest,
+                              tile_n: int, kc: int, n_tiles: int, gpq: int,
                               has_filter: bool):
     """Early-exit variant of ``_stream_topk_kernel``: anytime tile pruning.
 
@@ -581,79 +638,47 @@ def _stream_topk_prune_kernel(probe_ref, sizes_ref, table_ref, bounds_ref,
 
     Extra scratch: ``run_ref`` (1, kc) f32 VMEM — running top-kc dequantized
     distances of the *current query* (groups arrive query-major, ``gpq``
-    groups per query); ``thr_ref`` (1,) f32 SMEM — mirror of the running
-    kc-th best for scalar reads inside the DMA gate; ``latch_ref`` (2,) i32
-    SMEM — per-slot copy-issued flags for ``double_buffered_dma_gated``.
+    groups per query); ``thr_ref`` (1,) f32 SMEM — its kc-th best as a
+    scalar.
 
     A tile is skipped when its group's bound can't beat the running kc-th
     best: every candidate it could emit is >= bound >= threshold, and the
     running set already holds kc candidates from earlier flat positions, so
     with downstream's lowest-index tie-break the final top-kc is unchanged
-    (bit-identical for kc == keep). The decision is taken twice: once at
-    DMA-issue time through the latched gate (saving the copy itself — the
-    threshold only tightens afterwards, so a stale verdict is conservative),
-    and once fresh at compute time (saving the scan for tiles whose copy was
-    issued under a looser threshold). Tiles of the *next* query are always
-    copied — their query's threshold doesn't exist yet.
+    (bit-identical for kc == keep). A skipped tile saves its scan and
+    selection; its copy was already issued by the block pipeline, whose
+    index maps cannot see the running threshold.
 
-    Third output ``skip_ref`` (1, 1) i32: 1 iff this (group, tile) held a
-    valid probe but was pruned (its emitted candidates are sentinels).
+    Third output ``skip_ref`` (1, n_tiles, 1) i32: row ``ni`` is 1 iff this
+    (group, tile) held a valid probe but was pruned (its emitted candidates
+    are sentinels).
     """
+    del src_ref  # read by the index maps only
     if has_filter:
-        (fbits_ref, codes_hbm, vals_ref, slots_ref, skip_ref,
-         scratch, sem, run_ref, thr_ref, latch_ref) = rest
+        (fbits_ref, codes_ref, vals_ref, slots_ref, skip_ref,
+         run_ref, thr_ref) = rest
     else:
-        (codes_hbm, vals_ref, slots_ref, skip_ref,
-         scratch, sem, run_ref, thr_ref, latch_ref) = rest
+        codes_ref, vals_ref, slots_ref, skip_ref, run_ref, thr_ref = rest
         fbits_ref = None
     gi = pl.program_id(0)
     ni = pl.program_id(1)
-    step = gi * n_tiles + ni
     lid = probe_ref[gi]
-    total = g * n_tiles
-    qspan = gpq * n_tiles  # sequential steps belonging to one query
 
-    @pl.when(step % qspan == 0)
+    @pl.when((gi * n_tiles + ni) % (gpq * n_tiles) == 0)
     def _reset():  # first tile of a new query: no candidates seen yet
         run_ref[...] = jnp.full_like(run_ref, jnp.inf)
         thr_ref[0] = jnp.float32(jnp.inf)
 
-    start, wait, _ = _stream_dma_plan(
-        probe_ref, codes_hbm, scratch, sem,
-        tile_n=tile_n, n_tiles=n_tiles, total=total)
-
-    def want(s):
-        sc = jnp.minimum(s, total - 1)
-        gq = sc // n_tiles
-        ok = probe_ref[gq] >= 0
-        same_q = (gq // gpq) == (gi // gpq)
-        survives = bounds_ref[gq] < thr_ref[0]
-        return ok & (survives | ~same_q)
-
-    double_buffered_dma_gated(step, total, start, wait, want, latch_ref)
-
-    landed = latch_ref[step % 2] != 0
-    do_scan = landed & (bounds_ref[gi] < thr_ref[0])  # fresh re-check
+    do_scan = (lid >= 0) & (bounds_ref[gi] < thr_ref[0])
 
     @pl.when(do_scan)
     def _scan():
-        codes = _unpack_nibbles_i32(scratch[step % 2])  # (tn, M)
-        t = table_ref[0].astype(jnp.int32)
-        acc = _select_tree_acc(t, codes)[None, :]  # (1, tn)
-        slot = (jax.lax.broadcasted_iota(jnp.int32, (1, tile_n), 1)
-                + ni * tile_n)
-        acc = jnp.where(slot < sizes_ref[lid], acc, ACC_SENTINEL)
-        if fbits_ref is not None:
-            fb = fbits_ref[...].astype(jnp.int32)  # (1, W)
-            bits = jnp.stack([(fb >> j) & 1 for j in range(8)],
-                             axis=-1).reshape(1, -1)
-            tile_bits = jax.lax.dynamic_slice(
-                bits, (0, ni * tile_n), (1, tile_n))
-            acc = jnp.where(tile_bits > 0, acc, ACC_SENTINEL)
+        acc = _masked_tile_acc(table_ref, codes_ref, sizes_ref, lid,
+                               fbits_ref, tile_n=tile_n, ni=ni)
         vals, slots = _tile_topk(acc, ni * tile_n, kc)
-        vals_ref[...] = vals[:, None, :]
-        slots_ref[...] = slots[:, None, :]
-        skip_ref[...] = jnp.zeros_like(skip_ref)
+        _store_row(vals_ref, ni, vals)
+        _store_row(slots_ref, ni, slots)
+        _store_row(skip_ref, ni, jnp.zeros((1, 1), jnp.int32))
         # fold this tile's candidates into the query's running top-kc and
         # tighten the threshold (the same affine downstream applies)
         d = scales_ref[gi] * vals.astype(jnp.float32) + biases_ref[gi]
@@ -661,20 +686,21 @@ def _stream_topk_prune_kernel(probe_ref, sizes_ref, table_ref, bounds_ref,
         merged = _merge_smallest(
             jnp.concatenate([run_ref[...], d], axis=-1), kc)
         run_ref[...] = merged
-        thr_ref[0] = merged[0, kc - 1]
+        thr_ref[0] = jnp.max(merged)  # ascending: the kc-th best
 
     @pl.when(~do_scan)
     def _skip():  # invalid probe, or a tile the bound proved irrelevant
-        vals_ref[...] = jnp.full_like(vals_ref, ACC_SENTINEL)
-        slots_ref[...] = jnp.full_like(slots_ref, -1)
-        skip_ref[...] = jnp.full_like(skip_ref, (lid >= 0).astype(jnp.int32))
+        _store_row(vals_ref, ni, jnp.full((1, kc), ACC_SENTINEL, jnp.int32))
+        _store_row(slots_ref, ni, jnp.full((1, kc), -1, jnp.int32))
+        _store_row(skip_ref, ni,
+                   jnp.full((1, 1), 1, jnp.int32) * (lid >= 0).astype(jnp.int32))
 
 
 def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
                                  probe_ids: jax.Array, sizes: jax.Array, *,
                                  kc: int, tile_n: int = TILE_N,
                                  filter_bits: jax.Array | None = None,
-                                 interpret: bool = True,
+                                 interpret: bool,
                                  early_exit: bool = False,
                                  groups_per_query: int = 0,
                                  scales: jax.Array | None = None,
@@ -691,11 +717,11 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     Returns (vals (G, n_tiles, kc) i32, slots (G, n_tiles, kc) i32): per
     (group, cap-tile) the kc smallest quantized distances and their slot
     position inside the probed list, -1 slot = absent (padding past the
-    list's occupancy, a filtered-out row, or an invalid probe — whose DMA
+    list's occupancy, a filtered-out row, or an invalid probe — whose copy
     is skipped outright).
 
     The full (G, cap) accumulation never reaches HBM: selection happens in
-    VMEM on the tile the DMA just landed, so scan-stage writeback shrinks
+    VMEM on the tile just copied in, so scan-stage writeback shrinks
     by ~cap/kc. Keeping the per-tile top-kc is exact for any final
     selection of <= kc candidates (every survivor is within its own tile's
     top-kc), with ties resolved identically to ``masked_topk`` over the
@@ -706,7 +732,7 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     With ``early_exit`` (anytime search, docs/anytime.md) the kernel also
     prunes tiles whose group-level lower bound on any dequantized distance
     can't beat the query's running kc-th best — skipping the tile's scan
-    and, when the verdict lands before the copy is issued, its DMA. Requires
+    and selection (its copy is already issued by then). Requires
     ``groups_per_query`` (consecutive groups per query, > 0, dividing G) and
     the per-group dequantization affine ``scales``/``biases`` ((G,) f32,
     exactly what downstream selection applies). Returns a third array
@@ -721,10 +747,12 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     assert cap % tile_n == 0, (cap, tile_n)
     assert 1 <= kc <= tile_n, (kc, tile_n)
     n_tiles = cap // tile_n
+    probes = probe_ids.astype(jnp.int32)
     in_specs = [
-        pl.BlockSpec((1, m, 16), lambda gi, ni, pr, sz: (gi, 0, 0)),
+        pl.BlockSpec((1, 2 * k, mh), lambda gi, ni, *_: (gi, 0, 0)),
     ]
-    operands = [probe_ids, sizes, table_q8]
+    operands = [probes, sizes, _fetch_plan(probes, n_tiles),
+                _lut_planes(table_q8)]
     if early_exit:
         assert groups_per_query > 0 and g % groups_per_query == 0, (
             g, groups_per_query)
@@ -746,48 +774,57 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
         w = filter_bits.shape[-1]
         assert filter_bits.shape == (g, w) and w * 8 >= cap, (
             filter_bits.shape, g, cap)
-        in_specs.append(pl.BlockSpec((1, w), lambda gi, ni, pr, sz: (gi, 0)))
-        operands.append(filter_bits)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        # one (1, 1, W_t) block of words per (group, tile): every block's
+        # last two dims equal the array's, whatever tile_n is
+        if n_tiles == 1:
+            wt = w
+        else:
+            assert tile_n % 8 == 0, tile_n
+            wt = tile_n // 8
+        tiles = filter_bits[:, :n_tiles * wt].reshape(g * n_tiles, 1, wt)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, wt), lambda gi, ni, *_: (gi * n_tiles + ni, 0, 0)))
+        operands.append(tiles)
+    in_specs.append(_codes_spec(tile_n, n_tiles, mh))
     operands.append(list_codes)
+    per_group = lambda gi, ni, *_: (gi, 0, 0)  # noqa: E731
     out_specs = [
-        pl.BlockSpec((1, 1, kc), lambda gi, ni, pr, sz: (gi, ni, 0)),
-        pl.BlockSpec((1, 1, kc), lambda gi, ni, pr, sz: (gi, ni, 0)),
+        pl.BlockSpec((1, n_tiles, kc), per_group),
+        pl.BlockSpec((1, n_tiles, kc), per_group),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((g, n_tiles, kc), jnp.int32),
         jax.ShapeDtypeStruct((g, n_tiles, kc), jnp.int32),
     ]
-    scratch_shapes = [
-        pltpu.VMEM((2, tile_n, mh), jnp.uint8),
-        pltpu.SemaphoreType.DMA((2,)),
-    ]
+    scratch_shapes = []
+    has_filter = filter_bits is not None
     if early_exit:
-        out_specs.append(pl.BlockSpec((1, 1), lambda gi, ni, pr, sz: (gi, ni)))
-        out_shape.append(jax.ShapeDtypeStruct((g, n_tiles), jnp.int32))
-        scratch_shapes += [
+        out_specs.append(pl.BlockSpec((1, n_tiles, 1), per_group))
+        out_shape.append(jax.ShapeDtypeStruct((g, n_tiles, 1), jnp.int32))
+        scratch_shapes = [
             pltpu.VMEM((1, kc), jnp.float32),   # running top-kc (dequant)
-            pltpu.SMEM((1,), jnp.float32),      # threshold mirror
-            pltpu.SMEM((2,), jnp.int32),        # DMA-issued latches
+            pltpu.SMEM((1,), jnp.float32),      # threshold
         ]
         kernel = functools.partial(
             _stream_topk_prune_kernel, tile_n=tile_n, kc=kc,
-            n_tiles=n_tiles, g=g, gpq=groups_per_query,
-            has_filter=filter_bits is not None)
+            n_tiles=n_tiles, gpq=groups_per_query, has_filter=has_filter)
     else:
         kernel = functools.partial(_stream_topk_kernel, tile_n=tile_n, kc=kc,
-                                   n_tiles=n_tiles, g=g,
-                                   has_filter=filter_bits is not None)
+                                   has_filter=has_filter)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(g, n_tiles),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
+    if early_exit:
+        vals, slots, skipped = out
+        return vals, slots, skipped[..., 0]
+    return tuple(out)

@@ -8,7 +8,7 @@ Impl registries — ONE source of truth, everything else derives from it:
 
   ``GROUPED_IMPLS``  concrete grouped-scan formulations ('ref' jnp gather /
                      'select' VPU select-tree / 'mxu' one-hot GEMM /
-                     'stream' gather-free in-kernel list DMA);
+                     'stream' gather-free in-place list tiles);
   ``IMPLS``          the flat (shared-database) scan: the gathered subset
                      (no probe indirection exists in the flat layout);
   ``SCAN_IMPLS``     what callers may request: GROUPED_IMPLS + 'auto'.
@@ -59,7 +59,7 @@ from repro.kernels import rerank_kernel as rk
 GROUPED_IMPLS = ("ref", "select", "mxu", "stream")
 IMPLS = ("ref", "select", "mxu")
 SCAN_IMPLS = GROUPED_IMPLS + ("auto",)
-# Exact re-rank (stage 3) formulations: 'gathered' (jnp norms+GEMM over a
+# Exact re-rank (stage 3) formulations: 'gathered' (jnp Σ(q−x)² over a
 # gathered (Q, R, D) row copy), 'stream' (gather-free in-kernel row DMA +
 # fused top-k, kernels/rerank_kernel.py), 'auto' (timed dispatch, below).
 RERANK_CONCRETE = ("gathered", "stream")
@@ -124,7 +124,9 @@ def fastscan_distances(table_q8: jax.Array, packed_codes: jax.Array, *,
     codes_p = _pad_to(packed_codes, 0, tn)
 
     if impl == "select":
-        acc = fk.fastscan_select_tree(table_q8, codes_p, tile_n=tn, interpret=interp)
+        table_p = _pad_to(table_q8, 0, fk.ROWS_PER_STEP)
+        acc = fk.fastscan_select_tree(table_p, codes_p, tile_n=tn,
+                                      interpret=interp)
     elif impl == "mxu":
         tq = tile_q or _auto_tile(q, fk.TILE_Q)
         table_p = _pad_to(table_q8, 0, tq)
@@ -376,27 +378,23 @@ def _rerank_tile(r: int, tile_r: int = 0) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tile_r", "interpret"))
-def rerank_stream_topk(base: jax.Array, norms: jax.Array, q: jax.Array,
-                       cand_ids: jax.Array, *, k: int, tile_r: int = 0,
+def rerank_stream_topk(base: jax.Array, q: jax.Array, cand_ids: jax.Array,
+                       *, k: int, tile_r: int = 0,
                        interpret: bool | None = None
                        ) -> tuple[jax.Array, jax.Array]:
     """Gather-free exact re-rank over the in-place base (stage 3 hot path).
 
     base (N, D) f32 stays in HBM — the kernel DMAs only each query's
-    candidate rows; norms (N,) f32 = ``core.lists.base_norms(base)``;
-    q (Q, D) f32; cand_ids (Q, R) i32, -1 = padding. Returns
-    (vals (Q, k) f32 ascending, ids (Q, k) i32, -1 = absent), bit-identical
-    to ``engine.rerank.exact_rerank`` (same norms+GEMM expression, same
+    candidate rows; q (Q, D) f32; cand_ids (Q, R) i32, -1 = padding.
+    Returns (vals (Q, k) f32 ascending, ids (Q, k) i32, -1 = absent), the
+    same as ``engine.rerank.exact_rerank`` (same distance expression, same
     ``masked_topk`` tie-breaks — see kernels/rerank_kernel.py).
     """
     qq, r = cand_ids.shape
     interp = _default_interpret() if interpret is None else interpret
     tr = _rerank_tile(r, tile_r)
     cand_p = _pad_to(cand_ids.astype(jnp.int32), 1, tr, value=-1)
-    # only the survivors' norms are gathered up front: (Q, Rp) f32, a D×
-    # smaller gather than the (Q, R, D) row copy this path eliminates
-    xn = norms[jnp.maximum(cand_p, 0)]
-    vals, pos = rk.rerank_stream_topk(base, q, cand_p, xn, k=k, tile_r=tr,
+    vals, pos = rk.rerank_stream_topk(base, q, cand_p, k=k, tile_r=tr,
                                       interpret=interp)
     # pos follows masked_topk's position contract, so the shared sentinel-
     # preserving mapper applies as-is
@@ -417,10 +415,6 @@ _AUTOTUNE_CACHE: dict[tuple, TunedScan] = {}
 _AUTOTUNE_LOCK = threading.Lock()
 
 
-class _TraceEscapeError(RuntimeError):
-    """The autotune sweep was staged into an ambient trace instead of run."""
-
-
 def _grouped_tile_candidates(cap: int) -> tuple[int, ...]:
     """Cap-tile sizes worth timing: the shape-fit auto tile plus smaller
     power-of-two tiles (more grid parallelism / smaller VMEM blocks)."""
@@ -432,13 +426,45 @@ def _grouped_tile_candidates(cap: int) -> tuple[int, ...]:
     return tuple(sorted(cands))
 
 
+# Scoped VMEM a Pallas TPU kernel may use by default (16 MiB on v5e). The
+# autotune sweeps leave out tiles whose estimated working set exceeds it.
+_VMEM_BUDGET = 16 * 2**20
+
+
+def _lanes(n: int) -> int:
+    """A minor dim as VMEM lays it out: padded to whole 128-lane rows."""
+    return -(-n // 128) * 128
+
+
+def _scan_vmem_bytes(impl: str, tile_n: int, m: int) -> int:
+    """Largest live VMEM of one grouped-scan grid step (0 for 'ref').
+
+    Two buffered (tile_n, M//2) u8 code tiles, plus the i32 values the
+    formulation keeps live: 'mxu' its (tile_n, 16*M) repeated nibbles and
+    bf16 one-hot, the select-tree impls their two nibble planes and up to
+    16 (tile_n, M//2) tree temporaries.
+    """
+    if impl == "ref":
+        return 0
+    codes = 2 * tile_n * _lanes(m // 2)
+    if impl == "mxu":
+        return codes + tile_n * _lanes(16 * m) * (4 + 2)
+    return codes + 18 * tile_n * _lanes(m // 2) * 4
+
+
+def _rerank_vmem_bytes(tile_r: int, d: int) -> int:
+    """Stream re-rank scratch: two (tile_r, D) f32 row slots plus the
+    chunk's (q - x)² values."""
+    return 3 * tile_r * _lanes(d) * 4
+
+
 def _median_time_us(fn, iters: int = 3) -> float:
     out = fn()  # warmup: compile (or first interpret pass)
     if isinstance(out, jax.core.Tracer):
         # Staged into an ambient trace instead of executed — the "timing"
         # would measure tracing overhead, not the kernel. resolve_grouped_impl
         # escapes to a worker thread precisely to prevent this.
-        raise _TraceEscapeError("autotune sweep ran under an ambient jax trace")
+        raise RuntimeError("autotune sweep ran under an ambient jax trace")
     jax.block_until_ready(out)
     times = []
     for _ in range(iters):
@@ -498,9 +524,10 @@ def resolve_grouped_impl(g: int, cap: int, m: int, *, nlist: int | None = None,
     overstate the stream advantage's denominator. The fixed-seed synthetic
     data makes the sweep reproducible; the cache makes resolution
     deterministic for the life of the process (asserted in
-    tests/test_kernels.py). A candidate that fails to build at this shape
-    (e.g. an MXU tile blowing VMEM) is dropped, not fatal — 'ref' always
-    survives.
+    tests/test_kernels.py). Tiles whose working set would not fit the
+    kernel VMEM budget (``_scan_vmem_bytes``) are left out of the sweep up
+    front; any other failure of a candidate raises — a kernel
+    that does not build on this backend is a fault to see, not a verdict.
     """
     interp = _default_interpret() if interpret is None else interpret
     nl = int(g if nlist is None else nlist)
@@ -547,23 +574,15 @@ def _run_grouped_sweep(g: int, cap: int, m: int, nlist: int, fill: float,
         else:
             tiles = _grouped_tile_candidates(cap)
         for tn in tiles:
+            if _scan_vmem_bytes(impl, tn, m) > _VMEM_BUDGET:
+                continue
             if impl == "stream":
                 fn = functools.partial(fastscan_stream_grouped, table, store,
                                        probes, tile_n=tn, interpret=interp)
             else:
                 fn = functools.partial(fastscan_grouped, table, codes,
                                        impl=impl, tile_n=tn, interpret=interp)
-            try:
-                us = _median_time_us(fn)
-            except _TraceEscapeError:
-                raise  # a trace-escape regression, not a bad candidate
-            except Exception:  # candidate unbuildable at this shape: skip it
-                continue
-            sweep.append((impl, tn, us))
-    if not sweep:
-        raise RuntimeError(
-            f"autotune sweep produced no working candidate at "
-            f"(G={g}, cap={cap}, M={m}) — 'ref' should never fail")
+            sweep.append((impl, tn, _median_time_us(fn)))
     best = min(sweep, key=lambda r: r[2])
     tuned = TunedScan(
         impl=best[0], tile_n=best[1],
@@ -602,7 +621,7 @@ def resolve_rerank_impl(q: int, r: int, d: int, k: int, n: int, *,
     """Resolve ``rerank_impl='auto'`` at one (Q, R, D, k, N) re-rank
     signature (N = base-row count).
 
-    Times the gathered norms+GEMM fallback against the streaming kernel
+    Times the gathered fallback against the streaming kernel
     (x its chunk-tile candidates) on synthetic data of the workload shape
     (base rows capped at ``sweep_n_cap``, defaulting to the
     ``REPRO_RERANK_SWEEP_N_CAP`` env var then ``_RERANK_SWEEP_N_CAP``) and
@@ -611,8 +630,9 @@ def resolve_rerank_impl(q: int, r: int, d: int, k: int, n: int, *,
     scan verdicts. The cap shapes only the synthetic stand-in's size, never
     the key, so re-resolving with a bigger cap requires clearing the cached
     verdict first (``clear_autotune_cache(n=...)``). Both candidates are
-    bit-identical, so the verdict is purely a performance choice —
-    'gathered' always survives as the fallback.
+    bit-identical, so the verdict is purely a performance choice. Chunk
+    tiles whose scratch would not fit the VMEM budget are left out up
+    front; any other failure of a candidate raises.
     """
     interp = _default_interpret() if interpret is None else interpret
     cap = _rerank_sweep_n_cap() if sweep_n_cap is None else max(1, int(sweep_n_cap))
@@ -636,7 +656,6 @@ def _run_rerank_sweep(q: int, r: int, d: int, k: int, n: int, n_cap: int,
     rng = np.random.default_rng(0)
     n_sweep = max(r, min(n, n_cap))
     base = rng.standard_normal((n_sweep, d), dtype=np.float32)
-    norms = np.sum(base * base, axis=-1)
     queries = rng.standard_normal((q, d), dtype=np.float32)
     cand = rng.integers(0, n_sweep, (q, r), dtype=np.int32)
     sweep = []
@@ -645,22 +664,14 @@ def _run_rerank_sweep(q: int, r: int, d: int, k: int, n: int, n_cap: int,
         for tr in tiles:
             if impl == "gathered":
                 fn = functools.partial(rerank_mod.exact_rerank, base, queries,
-                                       cand, k, norms=norms)
-            else:
-                fn = functools.partial(rerank_stream_topk, base, norms,
-                                       queries, cand, k=k, tile_r=tr,
-                                       interpret=interp)
-            try:
-                us = _median_time_us(fn)
-            except _TraceEscapeError:
-                raise
-            except Exception:  # unbuildable candidate (scratch too big): skip
+                                       cand, k)
+            elif _rerank_vmem_bytes(tr, d) > _VMEM_BUDGET:
                 continue
-            sweep.append((impl, tr, us))
-    if not sweep:
-        raise RuntimeError(
-            f"re-rank autotune sweep produced no working candidate at "
-            f"(Q={q}, R={r}, D={d}, k={k}) — 'gathered' should never fail")
+            else:
+                fn = functools.partial(rerank_stream_topk, base, queries,
+                                       cand, k=k, tile_r=tr,
+                                       interpret=interp)
+            sweep.append((impl, tr, _median_time_us(fn)))
     best = min(sweep, key=lambda rec: rec[2])
     return TunedScan(
         impl=best[0], tile_n=best[1],

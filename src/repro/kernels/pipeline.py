@@ -1,10 +1,13 @@
-"""Shared double-buffered DMA pipeline for the streaming Pallas kernels.
+"""Double-buffered manual DMA pipeline for the streaming re-rank kernel.
 
-Every gather-free kernel in this repo has the same inner shape: a sequence of
-grid (or loop) steps, each of which DMAs one tile of in-place HBM data into
-VMEM scratch and then computes on it. Issuing the copy and immediately
-waiting on it (one DMA per step) leaves the DMA engine idle during compute
-and the compute units idle during the copy. The classic fix is a two-slot
+The re-rank kernel (``kernels/rerank_kernel.py``) runs a sequence of grid
+steps, each of which DMAs its candidate rows of the in-place HBM base into
+VMEM scratch and then computes on them. (The stream *scan* kernels fetch
+their code tiles through scalar-prefetch-indexed BlockSpecs instead, which
+Pallas double-buffers itself; see ``fastscan_kernel._codes_spec``.)
+Issuing the copy and immediately waiting on it (one DMA per step) leaves
+the DMA engine idle during compute and the compute units idle during the
+copy. The classic fix is a two-slot
 pipeline: while step ``t`` computes out of scratch slot ``t % 2``, step
 ``t+1``'s copy is already in flight into slot ``(t+1) % 2`` — two scratch
 buffers, two DMA semaphores, copy latency hidden behind compute.
@@ -23,15 +26,15 @@ slot ``(t+1) % 2`` was last read by step ``t-1``, whose compute finished
 before step ``t`` began, so overwriting it is race-free.
 
 Interpret mode executes copies synchronously, so the pipeline degenerates to
-the one-DMA-per-step schedule with identical results — bit-identity of the
-refactor is asserted in ``tests/test_stream_rerank.py``.
+the one-DMA-per-step schedule with identical results — bit-identity is
+asserted in ``tests/test_stream_rerank.py``.
 """
 from __future__ import annotations
 
 from jax.experimental import pallas as pl
 
 
-def double_buffered_dma(step, total: int, start, wait, valid) -> None:
+def double_buffered_dma(step, total: int, start, wait) -> None:
     """Run one step of a two-slot DMA pipeline over ``total`` sequential steps.
 
     step:  traced i32 — this step's position in the global sequential order
@@ -43,76 +46,19 @@ def double_buffered_dma(step, total: int, start, wait, valid) -> None:
     wait:  ``wait(s, slot)`` blocks until step ``s``'s copy/copies into
            ``slot`` have landed. Must mirror ``start`` transfer-for-transfer
            (each DMA wait consumes exactly one start's semaphore signals).
-    valid: ``valid(s)`` — traced bool, False for steps whose transfer is
-           skipped entirely (e.g. a ``-1`` probe). Evaluated for ``s`` up to
-           ``total`` (non-short-circuiting ``&``), so implementations must
-           clamp any indexing on ``s``.
 
     After this returns, step ``step``'s data is resident in slot
-    ``step % 2`` (when valid) and step ``step + 1``'s transfer is in flight.
-    """
-    nxt = step + 1
-
-    @pl.when((step == 0) & valid(step))
-    def _prime():  # first step of the sequence: nothing is in flight yet
-        start(step, 0)
-
-    @pl.when((nxt < total) & valid(nxt))
-    def _prefetch():  # overlap the next tile's copy with this tile's compute
-        start(nxt, nxt % 2)
-
-    @pl.when(valid(step))
-    def _land():
-        wait(step, step % 2)
-
-
-def double_buffered_dma_gated(step, total: int, start, wait, want,
-                              latch) -> None:
-    """Two-slot pipeline whose skip predicate may change between steps.
-
-    ``double_buffered_dma`` evaluates ``valid(s)`` independently at the
-    start-issue site (step ``s - 1``) and the wait site (step ``s``). That is
-    only sound when the predicate is a pure function of ``s``. An early-exit
-    kernel's skip decision also reads a *mutable* threshold (the running
-    k-th-best distance), which can tighten between those two evaluations —
-    the wait would then see ``False`` for a copy that was actually issued,
-    leaking an unconsumed DMA semaphore signal into the next step that reuses
-    the slot.
-
-    This variant evaluates ``want(s)`` exactly once, at the moment step
-    ``s``'s copy would be issued, and records the verdict in ``latch`` (SMEM
-    scratch, shape (2,), i32, indexed by ``s % 2``). The wait site consults
-    the latch, never the predicate, so every started copy is waited and every
-    skipped copy stays skipped — the slots cannot desync no matter how the
-    threshold moves. ``want`` must still clamp indexing on ``s`` (evaluated
-    for ``s`` up to ``total``). Skips based on a stale-but-monotone threshold
-    are conservative: the threshold only tightens, so a copy issued under an
-    older looser threshold is merely wasted bandwidth, never a correctness
-    hazard; the caller re-checks the fresh bound before computing.
-
-    Returns nothing; after it, ``latch[step % 2] != 0`` iff step ``step``'s
-    data is resident in slot ``step % 2``.
+    ``step % 2`` and step ``step + 1``'s transfer is in flight.
     """
     nxt = step + 1
 
     @pl.when(step == 0)
-    def _prime():  # decide + issue (or latch the skip of) the first copy
-        w = want(step)
-        latch[0] = w.astype(latch.dtype)
-
-        @pl.when(w)
-        def _go():
-            start(step, 0)
+    def _prime():  # first step of the sequence: nothing is in flight yet
+        start(step, 0)
 
     @pl.when(nxt < total)
-    def _prefetch():
-        w = want(nxt)
-        latch[nxt % 2] = w.astype(latch.dtype)
+    def _prefetch():  # overlap the next tile's copy with this tile's compute
+        start(nxt, nxt % 2)
 
-        @pl.when(w)
-        def _go():
-            start(nxt, nxt % 2)
+    wait(step, step % 2)
 
-    @pl.when(latch[step % 2] != 0)
-    def _land():
-        wait(step, step % 2)

@@ -229,7 +229,6 @@ def _serialize_single(engine: SearchEngine, st, directory: str,
     arrays["codebook"] = np.asarray(st.index.codebook.codewords)
     if st.base is not None:
         arrays["base"] = np.asarray(st.base)
-        arrays["base_norms"] = np.asarray(st.base_norms)
     if st.live_bits is not None:
         arrays["live_bits"] = np.asarray(st.live_bits)
     if engine.ns_member is not None:
@@ -287,7 +286,6 @@ def _serialize_sharded(engine: ShardedEngine, st: _ShardState,
         sh["gids"] = np.asarray(st.gids_s[j])
         if st.base_s is not None:
             sh["base"] = np.asarray(st.base_s[j])
-            sh["norms"] = np.asarray(st.norms_s[j])
         entries = _write_segments(directory, shard_dir, sh, parent_sh[j],
                                   stats)
         sub = json.dumps({"shard": j, "segments": entries},
@@ -473,11 +471,9 @@ def _deserialize_single(directory: str, manifest: dict) -> SearchEngine:
         hnsw_m=int(meta["hnsw_m"]),
         ef_construction=int(meta["ef_construction"]),
         namespaces=get("ns_member") if "ns_member" in segs else None)
-    # the constructor recomputes norms/live bits (bitwise-equal by
+    # the constructor recomputes the live bits (bitwise-equal by
     # construction); install the snapshotted ones + epoch verbatim anyway
     engine._state = engine._state._replace(
-        base_norms=(jnp.asarray(get("base_norms"))
-                    if "base_norms" in segs else None),
         live_bits=(jnp.asarray(get("live_bits"))
                    if "live_bits" in segs else None),
         epoch=int(meta["epoch"]),
@@ -531,13 +527,13 @@ def _deserialize_sharded(directory: str, manifest: dict) -> ShardedEngine:
         real_s=stack("real").astype(bool),
         base_s=stack("base") if has_base else None,
         gids_s=stack("gids"),
-        norms_s=stack("norms") if has_base else None,
         live_s=pack_filter_mask(lists_s.ids >= 0) if n_tomb else None,
         rows_used=tuple(int(r) for r in meta["rows_used"]),
         epoch=int(meta["epoch"]), n_tombstones=n_tomb)
     engine._mutate_lock = threading.RLock()
     engine._locator = None
     engine._wal = None
+    engine._mesh_copy = None
     return engine
 
 

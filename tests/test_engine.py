@@ -103,27 +103,24 @@ def test_rerank_bitmatches_brute_force_on_candidate_set():
     got = exact_distances(ds.base, q, cand)
 
     # candidate-restricted brute force, written independently in the same
-    # norms+GEMM formulation exact_distances now uses ((‖q‖² − 2q·x) + ‖x‖²,
-    # mul+sum contractions): same math, same shapes -> must agree
-    # bit-for-bit (the subtraction form drifts by ~1 ulp and is guarded
-    # separately on integer data in tests/test_stream_rerank.py). jit'd so
-    # both sides get XLA's fused reduction order.
+    # subtraction form exact_distances uses (Σ(q−x)², one sum over D): same
+    # math, same shapes -> must agree bit-for-bit. jit'd so both sides get
+    # XLA's fused reduction order.
     def bf(b, qq, c):
-        x = b[jnp.maximum(c, 0)]
-        return jnp.maximum((jnp.sum(qq * qq, -1)[:, None]
-                            - 2.0 * jnp.sum(qq[:, None, :] * x, -1))
-                           + jnp.sum(b * b, -1)[jnp.maximum(c, 0)], 0.0)
+        return jnp.sum((b[jnp.maximum(c, 0)] - qq[:, None, :]) ** 2, -1)
     want = jax.jit(bf)(ds.base, q, cand)
     valid = np.asarray(cand >= 0)
     np.testing.assert_array_equal(np.asarray(got)[valid], np.asarray(want)[valid])
     assert np.all(np.isinf(np.asarray(got)[~valid]))
 
-    # and anchor against float64 numpy ground truth (f32 rounding only)
+    # and anchor against float64 numpy ground truth: every summand is >= 0,
+    # so f32 rounding stays within a few ulp of the distance itself (the
+    # expanded (‖q‖² − 2q·x) + ‖x‖² form cancels and misses this by ~10x)
     base64 = np.asarray(ds.base, np.float64)
     q64 = np.asarray(q, np.float64)
     want64 = ((base64[np.maximum(np.asarray(cand), 0)]
                - q64[:, None, :]) ** 2).sum(-1)
-    np.testing.assert_allclose(np.asarray(got)[valid], want64[valid], rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got)[valid], want64[valid], rtol=1e-6)
 
     # and the re-ranked top-k is the brute-force order on that set
     vals, ids = exact_rerank(ds.base, q, cand, 10)
@@ -230,7 +227,7 @@ def test_partition_base_covers_every_row_once_without_replication():
     s = 4
     cen_s, lists_s, real_s = partition_lists(eng.index.lists,
                                              eng.index.centroids, s)
-    base_s, gids_s, local_ids, norms_s = partition_base(lists_s, ds.base)
+    base_s, gids_s, local_ids = partition_base(lists_s, ds.base)
     n, d = ds.base.shape
     # each global id appears exactly once across all shards' gids
     g = np.asarray(gids_s).reshape(-1)
@@ -249,15 +246,9 @@ def test_partition_base_covers_every_row_once_without_replication():
         np.testing.assert_array_equal(bs[j][li[j][valid[j]]], b[gi[j][valid[j]]])
         np.testing.assert_array_equal(np.asarray(gids_s)[j][li[j][valid[j]]],
                                       gi[j][valid[j]])
-    # norms ride along: norms_s[shard, local] == base_norms(base)[global]
-    # bitwise (sliced from ONE full-base computation, not re-derived), 0 at
-    # padding
-    from repro.core.lists import base_norms
-    nrm = np.asarray(base_norms(ds.base))
-    ns = np.asarray(norms_s)
+    # padding rows of each slice are zero and map to no global id
     gv = g >= 0
-    np.testing.assert_array_equal(ns.reshape(-1)[gv], nrm[g[gv]])
-    assert (ns.reshape(-1)[~gv] == 0).all()
+    assert (bs.reshape(-1, d)[~gv] == 0).all()
 
 
 def test_sharded_rerank_on_local_base_matches_replicated_semantics():

@@ -25,11 +25,11 @@ from hypothesis import HealthCheck, given, settings
 from hypothesis import strategies as hst
 
 from repro.core import ivf
-from repro.core.lists import (ListStore, append_rows, build_lists,
-                              compact_lists, filter_from_attrs, filter_words,
-                              grow_cap, live_counts, live_filter_bits,
-                              locate_rows, pack_filter_mask, tombstone_counts,
-                              tombstone_rows)
+from repro.core.lists import (ListStore, aligned_cap, append_rows,
+                              build_lists, compact_lists, filter_from_attrs,
+                              filter_words, grow_cap, live_counts,
+                              live_filter_bits, locate_rows, pack_filter_mask,
+                              tombstone_counts, tombstone_rows)
 from repro.data import vectors
 from repro.engine import EngineConfig, SearchEngine, ShardedEngine
 from repro.kernels import ops as ops_mod
@@ -237,6 +237,19 @@ def test_grow_cap_pads_and_refuses_shrink():
     assert grow_cap(st, 8) is st
     with pytest.raises(ValueError):
         grow_cap(st, 4)
+
+
+@pytest.mark.parametrize("fullest,cap", [
+    (1, 128), (5, 128), (128, 128), (129, 256), (1000, 1024), (1024, 1024),
+    (1025, 2048), (4000, 4096)])
+def test_default_cap_is_a_stream_tile_multiple(fullest, cap):
+    """Without an explicit cap, build_lists rounds the fullest list up so the
+    stream kernels always get a lane-aligned tile: 128-row granules up to
+    1024, whole 1024-row tiles beyond."""
+    assert aligned_cap(fullest) == cap
+    st = _tiny_store(nlist=2, cap=None, rows_per_list=(fullest, 3))
+    assert st.cap == cap and st.codes.shape == (2, cap, 2)
+    assert int(st.sizes[0]) == fullest
 
 
 def test_compact_lists_preserves_survivor_order():

@@ -4,10 +4,10 @@ The 'stream' re-rank impl must be *bit-identical* to the gathered
 ``exact_rerank`` — through the raw kernel wrapper, ``finalize_candidates``,
 and the whole engine (``search`` / ``search_jit`` / ``ShardedEngine`` on
 both top-k drivers). Both impls share one distance expression
-(``rerank_kernel.norms_gemm_dists``), so every comparison here is
-``assert_array_equal``, never allclose. Also covers: the norms+GEMM rewrite
-of the gathered fallback (tolerance-zero parity against the subtraction
-form on integer-valued data, where f32 is exact for both), the
+(``rerank_kernel.sq_l2_dists``), so every comparison here is
+``assert_array_equal``, never allclose. Also covers: the gathered
+``exact_distances`` against an independent subtraction-form oracle
+(tolerance zero), the
 double-buffered DMA refactor of the stream *scan* kernels (bit-identity
 across multi-tile grids that exercise the two-slot rotation), re-rank
 autotune dispatch, the v2 persistence schema + v1 migration, and the
@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 
 from repro.core import topk as topk_mod
-from repro.core.lists import base_norms
 from repro.data import vectors
 from repro.engine import EngineConfig, SearchEngine, ShardedEngine
 from repro.engine import rerank as rerank_mod
@@ -31,7 +30,7 @@ from repro.launch.hlo_analysis import xla_cost_dict
 
 
 def _case(n=300, d=16, q=4, r=24, seed=0, ties=False):
-    """(base, norms, queries, cand) — ``ties=True`` draws base rows from a
+    """(base, queries, cand) — ``ties=True`` draws base rows from a
     tiny integer lattice so duplicate rows (hence exactly-equal distances)
     genuinely occur and the lowest-position tie-break is exercised."""
     rng = np.random.default_rng(seed)
@@ -42,14 +41,14 @@ def _case(n=300, d=16, q=4, r=24, seed=0, ties=False):
     base = jnp.asarray(base)
     queries = jnp.asarray(rng.normal(size=(q, d)).astype(np.float32))
     cand = rng.integers(0, n, (q, r)).astype(np.int32)
-    return base, base_norms(base), queries, cand
+    return base, queries, cand
 
 
-def _assert_rerank_parity(base, norms, queries, cand, k, **kw):
+def _assert_rerank_parity(base, queries, cand, k, **kw):
     want_v, want_i = rerank_mod.exact_rerank(base, queries, jnp.asarray(cand),
-                                             k, norms=norms)
-    got_v, got_i = ops.rerank_stream_topk(base, norms, queries,
-                                          jnp.asarray(cand), k=k, **kw)
+                                             k)
+    got_v, got_i = ops.rerank_stream_topk(base, queries, jnp.asarray(cand),
+                                          k=k, **kw)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
 
@@ -60,20 +59,20 @@ def _assert_rerank_parity(base, norms, queries, cand, k, **kw):
 
 @pytest.mark.parametrize("tile_r", [0, 8, 16])
 def test_stream_rerank_matches_gathered(tile_r):
-    base, norms, q, cand = _case()
-    _assert_rerank_parity(base, norms, q, cand, 10, tile_r=tile_r)
+    base, q, cand = _case()
+    _assert_rerank_parity(base, q, cand, 10, tile_r=tile_r)
 
 
 def test_stream_rerank_ragged_and_all_invalid_rows():
     """-1 padding mid-pool, a fully-invalid query, and R < r*k raggedness:
     absent slots come back (+inf, -1) exactly like masked_topk's."""
-    base, norms, q, cand = _case(q=4, r=24)
+    base, q, cand = _case(q=4, r=24)
     cand[0, 5:] = -1          # ragged: only 5 live candidates (< k)
     cand[1, :] = -1           # all invalid -> whole row absent
     cand[2, ::2] = -1         # interleaved padding
-    _assert_rerank_parity(base, norms, q, cand, 10, tile_r=8)
-    vals, ids = ops.rerank_stream_topk(base, norms, q, jnp.asarray(cand),
-                                       k=10, tile_r=8)
+    _assert_rerank_parity(base, q, cand, 10, tile_r=8)
+    vals, ids = ops.rerank_stream_topk(base, q, jnp.asarray(cand), k=10,
+                                       tile_r=8)
     assert (np.asarray(ids)[1] == -1).all()
     assert np.isinf(np.asarray(vals)[1]).all()
     assert (np.asarray(ids)[0, 5:] == -1).all()
@@ -82,8 +81,8 @@ def test_stream_rerank_ragged_and_all_invalid_rows():
 def test_stream_rerank_single_candidate_and_single_query():
     # k == R == 1: the smallest legal selection (k > R is rejected by the
     # gathered oracle's lax.top_k, so the contract floor is k <= R)
-    base, norms, q, cand = _case(q=1, r=1)
-    _assert_rerank_parity(base, norms, q, cand, 1)
+    base, q, cand = _case(q=1, r=1)
+    _assert_rerank_parity(base, q, cand, 1)
 
 
 def test_stream_rerank_ties_resolve_like_masked_topk():
@@ -91,14 +90,14 @@ def test_stream_rerank_ties_resolve_like_masked_topk():
     running-merge must pick the lowest candidate position, byte-for-byte
     like masked_topk — across chunk boundaries too (tile_r=4 splits the
     pool into 6 chunks)."""
-    base, norms, q, cand = _case(n=40, d=4, q=5, r=24, ties=True)
-    _assert_rerank_parity(base, norms, q, cand, 8, tile_r=4)
+    base, q, cand = _case(n=40, d=4, q=5, r=24, ties=True)
+    _assert_rerank_parity(base, q, cand, 8, tile_r=4)
 
 
 def test_stream_rerank_multi_chunk_shapes():
     """R >> tile_r drives many double-buffered chunks per query."""
-    base, norms, q, cand = _case(n=800, d=24, q=3, r=160)
-    _assert_rerank_parity(base, norms, q, cand, 10, tile_r=16)
+    base, q, cand = _case(n=800, d=24, q=3, r=160)
+    _assert_rerank_parity(base, q, cand, 10, tile_r=16)
 
 
 def test_stream_rerank_duplicate_candidates_behave_like_gathered():
@@ -107,30 +106,29 @@ def test_stream_rerank_duplicate_candidates_behave_like_gathered():
     hand-composed pool CAN contain duplicates, and the two impls must then
     misbehave identically (the duplicate id may appear twice in the top-k,
     positions still lowest-first)."""
-    base, norms, q, cand = _case(q=3, r=16)
+    base, q, cand = _case(q=3, r=16)
     cand[:, 8:] = cand[:, :8]          # every candidate duplicated
-    _assert_rerank_parity(base, norms, q, cand, 10, tile_r=8)
+    _assert_rerank_parity(base, q, cand, 10, tile_r=8)
 
 
 def test_stream_rerank_k_exceeds_live_candidates():
     """k > live candidates: exactly the live ones come back, then -1s."""
-    base, norms, q, cand = _case(q=2, r=6)
+    base, q, cand = _case(q=2, r=6)
     cand[:, 3:] = -1
-    _assert_rerank_parity(base, norms, q, cand, 6, tile_r=8)
+    _assert_rerank_parity(base, q, cand, 6, tile_r=8)
 
 
 # ---------------------------------------------------------------------------
-# the norms+GEMM rewrite of the gathered fallback
+# the gathered fallback against an independent oracle
 # ---------------------------------------------------------------------------
 
 def test_exact_distances_norms_gemm_equals_subtraction_form_exactly():
-    """Tolerance-ZERO parity of the rewritten gathered ``exact_distances``
-    against the subtraction form it replaced — on integer-valued f32 data,
-    where every product/sum in both formulations is an exactly-representable
-    integer (all magnitudes << 2^24), so the algebraic identity
-    ``Σ(q−x)² == (‖q‖² − 2q·x) + ‖x‖²`` must hold bit-for-bit. (On generic
-    float data the two round differently by design; the f64-anchored
-    accuracy test lives in tests/test_engine.py.)"""
+    """Tolerance-ZERO parity of the gathered ``exact_distances`` against an
+    independently written subtraction form ``Σ(x−q)²`` — on integer-valued
+    f32 data, where every difference, square and partial sum is an
+    exactly-representable integer (all magnitudes << 2^24), so no reduction
+    order can make them differ. (The f64-anchored accuracy test on generic
+    float data lives in tests/test_engine.py.)"""
     rng = np.random.default_rng(7)
     n, d, q, r = 200, 16, 6, 30
     base = jnp.asarray(rng.integers(-9, 10, (n, d)).astype(np.float32))
@@ -149,7 +147,7 @@ def test_exact_distances_norms_gemm_equals_subtraction_form_exactly():
 def test_finalize_candidates_routes_impls_identically():
     """finalize_candidates under 'gathered' vs 'stream' (and an unknown
     impl raising) — same (vals, ids, reranked) bit-for-bit."""
-    base, norms, q, cand = _case(q=3, r=20)
+    base, q, cand = _case(q=3, r=20)
     rng = np.random.default_rng(3)
     flat_d = jnp.asarray(rng.normal(size=(3, 64)).astype(np.float32)) ** 2
     flat_ids = jnp.asarray(rng.permutation(300)[:64][None, :].repeat(3, 0)
@@ -157,12 +155,12 @@ def test_finalize_candidates_routes_impls_identically():
     out = {}
     for impl in ("gathered", "stream"):
         out[impl] = rerank_mod.finalize_candidates(
-            flat_d, flat_ids, base, q, 10, 3, norms=norms, rerank_impl=impl)
+            flat_d, flat_ids, base, q, 10, 3, rerank_impl=impl)
     for a, b in zip(out["gathered"], out["stream"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     with pytest.raises(ValueError, match="unknown rerank impl"):
         rerank_mod.finalize_candidates(flat_d, flat_ids, base, q, 10, 3,
-                                       norms=norms, rerank_impl="simd")
+                                       rerank_impl="simd")
 
 
 # ---------------------------------------------------------------------------
@@ -320,17 +318,17 @@ def test_resolve_rerank_impl_sweeps_both_and_caches():
         ops.resolve_rerank_impl(2, 12, 16, 5, 5000)
         assert ops.autotune_cache_size() == 2
         # 'auto' through the engine path is bit-identical to both concretes
-        base, norms, q, cand = _case(q=2, r=12, d=16)
+        base, q, cand = _case(q=2, r=12, d=16)
         want = rerank_mod.finalize_candidates(
             jnp.abs(jnp.asarray(np.random.default_rng(0).normal(
                 size=(2, 40)).astype(np.float32))),
             jnp.asarray(np.arange(80, dtype=np.int32).reshape(2, 40)),
-            base, q, 5, 2, norms=norms, rerank_impl="gathered")
+            base, q, 5, 2, rerank_impl="gathered")
         got = rerank_mod.finalize_candidates(
             jnp.abs(jnp.asarray(np.random.default_rng(0).normal(
                 size=(2, 40)).astype(np.float32))),
             jnp.asarray(np.arange(80, dtype=np.int32).reshape(2, 40)),
-            base, q, 5, 2, norms=norms, rerank_impl="auto")
+            base, q, 5, 2, rerank_impl="auto")
         for a, b in zip(want, got):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     finally:
@@ -400,14 +398,13 @@ def test_stream_rerank_stage_bytes_accessed_4x_below_gathered():
     rng = np.random.default_rng(17)
     n, d, q, k, r = 4096, 128, 32, 10, 4
     base = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-    norms = base_norms(base)
     queries = jnp.asarray(rng.normal(size=(q, d)).astype(np.float32))
     cand = jnp.asarray(rng.integers(0, n, (q, r * k)).astype(np.int32))
     gathered = jax.jit(functools.partial(rerank_mod.exact_rerank, k=k))
     streamed = jax.jit(functools.partial(ops.rerank_stream_topk, k=k))
     b_gather = xla_cost_dict(gathered.lower(
-        base, queries, cand, norms=norms).compile()).get("bytes accessed", 0.0)
+        base, queries, cand).compile()).get("bytes accessed", 0.0)
     b_stream = xla_cost_dict(streamed.lower(
-        base, norms, queries, cand).compile()).get("bytes accessed", 0.0)
+        base, queries, cand).compile()).get("bytes accessed", 0.0)
     assert b_gather > 0 and b_stream > 0
     assert b_stream * 4 <= b_gather, (b_stream, b_gather)

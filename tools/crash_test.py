@@ -29,6 +29,12 @@ asserts (a) the promoted replica is bit-identical to a from-scratch
 rebuild over exactly the acked prefix, (b) the deposed primary's next
 append and ship both raise ``FencedError``, and (c) standby reads serve
 before, during, and after the transition.
+
+The drill tests durability, not the device: the parent and every child run
+JAX on the CPU (``JAX_PLATFORMS=cpu``, set in ``main`` and passed to each
+child explicitly). Two JAX processes alive at once — the parent holding an
+engine while its next child builds one — could not share one accelerator
+chip, which belongs to one process at a time.
 """
 from __future__ import annotations
 
@@ -50,6 +56,11 @@ NLIST = 16
 D = 32
 M = 8
 ACK = "ACK"
+
+
+def _cpu_env() -> dict[str, str]:
+    """Child environment: this process's, with JAX pinned to the CPU."""
+    return {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 
 def build_engine():
@@ -148,7 +159,7 @@ def run_replication(kill_at: int, steps: int, seed: int,
         [sys.executable, os.path.abspath(__file__), "--child",
          "--replication", "--dir", directory, "--steps", str(steps),
          "--seed", str(seed)],
-        stdout=subprocess.PIPE, text=True,
+        stdout=subprocess.PIPE, text=True, env=_cpu_env(),
         cwd=str(pathlib.Path(__file__).resolve().parent.parent))
     acked = -1
     try:
@@ -251,7 +262,7 @@ def run_one(kill_at: int, steps: int, seed: int, directory: str) -> bool:
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--dir", directory, "--steps", str(steps), "--seed", str(seed)],
-        stdout=subprocess.PIPE, text=True,
+        stdout=subprocess.PIPE, text=True, env=_cpu_env(),
         cwd=str(pathlib.Path(__file__).resolve().parent.parent))
     acked = -1
     try:
@@ -314,6 +325,8 @@ def main() -> int:
     ap.add_argument("--replication", action="store_true",
                     help="run the ship/promote failover drill instead")
     args = ap.parse_args()
+    # before the first jax import (all of them are inside the functions)
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     if args.child:
         if args.replication:
