@@ -21,9 +21,13 @@ and checks what comes out against the plain jax.numpy engine (``ref`` scan,
                 distances bit-identical or within 1e-6 relative, 0 compiles
                 after warmup, recall@1 / recall@10 against brute force
 
+The serve phase also holds the served distances to a float64 NumPy
+sum((q - x)^2) over the returned ids, within the same 1e-6 relative.
+
 With ``--chips 4`` only the sharded phase runs (after device + build):
 ShardedEngine(stream engine, 4) through its shard_map driver on a 4-device
-mesh, compared with the same engine's vmap driver on one device.
+mesh, compared with the same engine's vmap driver on one device; then the
+bytes of every live array on each device, which must show one shard each.
 
 The last line of stdout is one JSON object:
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -186,6 +190,23 @@ def _compare(name: str, dists, ids, ref_dists, ref_ids) -> None:
                              f"{MAX_REL_GAP}")
 
 
+def _check_exact(name: str, dists, ids, base, queries) -> None:
+    """Served distances against NumPy float64 sum((q - x)^2) over the
+    returned ids — a reference that shares no code with the engine."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    real = ids >= 0
+    x = np.asarray(jnp.take(base, np.maximum(ids, 0), axis=0), np.float64)
+    exact = np.sum((queries[:, None, :].astype(np.float64) - x) ** 2, axis=-1)
+    gap = float(np.max(np.abs(dists - exact)[real]
+                       / np.maximum(exact[real], 1e-30), initial=0.0))
+    print(f"{name}: largest relative gap to float64 {gap!r}", flush=True)
+    if gap > MAX_REL_GAP:
+        raise AssertionError(f"{name}: relative distance gap {gap} > "
+                             f"{MAX_REL_GAP}")
+
+
 def _serve(ds, engine_stream, engine_ref) -> None:
     import numpy as np
 
@@ -219,13 +240,29 @@ def _serve(ds, engine_stream, engine_ref) -> None:
           f"{compiles} compiles after warmup, recall@1 {r1!r} "
           f"recall@10 {r10!r} (floor {RECALL10_FLOOR})", flush=True)
     _compare("serve vs reference", dists, ids, ref_dists, ref_ids)
+    _check_exact("serve vs float64", dists, ids, ds.base, queries)
     if compiles:
         raise AssertionError(f"{compiles} compiles after warmup")
     if r10 < RECALL10_FLOOR:
         raise AssertionError(f"recall@10 {r10} below {RECALL10_FLOOR}")
 
 
-def _sharded(ds, engine_stream, chips: int) -> None:
+def _bytes_per_device(jax) -> dict:
+    """Bytes of every live array on each device, shard by shard."""
+    import gc
+
+    gc.collect()
+    held = {d: 0 for d in jax.devices()}
+    for arr in jax.live_arrays():
+        for shard in arr.addressable_shards:
+            held[shard.device] += shard.data.nbytes
+    return held
+
+
+def _sharded(n: int, chips: int) -> None:
+    """ShardedEngine's shard_map driver on ``chips`` devices against its
+    vmap driver on one. Only the sharded engine is kept alive, so the
+    bytes each device holds afterwards are its shard and little else."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -236,30 +273,35 @@ def _sharded(ds, engine_stream, chips: int) -> None:
     if len(devs) < chips:
         raise AssertionError(f"--chips {chips} but JAX sees {len(devs)} "
                              "devices")
-    sharded = ShardedEngine(engine_stream, chips)
+    ds, engine_stream = _build(n)[:2]
     queries = np.asarray(ds.queries, np.float32)
+    sharded = ShardedEngine(engine_stream, chips)
+    del ds, engine_stream
     batches = [queries[s:s + BATCH] for s in range(0, len(queries), BATCH)]
-    t0 = time.perf_counter()
-    ref = [sharded.search(b, K) for b in batches]          # vmap, one device
-    t_vmap = time.perf_counter() - t0
-    mesh = Mesh(np.array(devs[:chips]), ("shards",))
-    t0 = time.perf_counter()
-    got = [sharded.search(b, K, mesh=mesh) for b in batches]
-    t_mesh = time.perf_counter() - t0
-    held = sharded.shard_bytes(mesh)
+
+    def run(**kw):
+        t0 = time.perf_counter()
+        res = [sharded.search(b, K, **kw) for b in batches]
+        dists = np.concatenate([np.asarray(r.dists) for r in res])
+        ids = np.concatenate([np.asarray(r.ids) for r in res])
+        return dists, ids, time.perf_counter() - t0
+
+    ref_dists, ref_ids, t_vmap = run()                      # vmap, one device
+    dists, ids, t_mesh = run(mesh=Mesh(np.array(devs[:chips]), ("shards",)))
+    held = _bytes_per_device(jax)
     total = sum(held.values())
+    in_use = {str(d): (d.memory_stats() or {}).get("bytes_in_use",
+                                                   "not reported")
+              for d in devs[:chips]}
     print(f"sharded: {chips} shards, shard_map on {chips} devices "
-          f"({t_mesh:.1f}s) vs vmap on one ({t_vmap:.1f}s) | state bytes per "
-          f"device: { {str(d): b for d, b in held.items()} } of {total}",
-          flush=True)
-    if len(held) != chips or max(held.values()) > total // chips + total // 100:
+          f"({t_mesh:.1f}s) vs vmap on one ({t_vmap:.1f}s) | live array bytes"
+          f" per device: { {str(d): b for d, b in held.items()} } of {total}"
+          f" | bytes_in_use {in_use}", flush=True)
+    # one shard each, plus the small replicated tables (global centroids,
+    # codebook) and results; a whole copy left on one device is ~chips x
+    if max(held[d] for d in devs[:chips]) > 2 * total / chips:
         raise AssertionError("shard state is not split one shard per device")
-    cat = np.concatenate
-    _compare("shard_map vs vmap",
-             cat([np.asarray(r.dists) for r in got]),
-             cat([np.asarray(r.ids) for r in got]),
-             cat([np.asarray(r.dists) for r in ref]),
-             cat([np.asarray(r.ids) for r in ref]))
+    _compare("shard_map vs vmap", dists, ids, ref_dists, ref_ids)
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -280,12 +322,12 @@ def main(argv: list[str] | None = None) -> int:
         print(f"no TPU found: --n {args.n} needs the chip; rehearse off it "
               f"with --n <= {MAX_N_OFF_TPU}", file=sys.stderr)
         return 1
-    ds, engine_stream, engine_ref = _build(args.n)
     if args.chips == 1:
+        ds, engine_stream, engine_ref = _build(args.n)
         _stage_parity(ds, engine_stream)
         _serve(ds, engine_stream, engine_ref)
     else:
-        _sharded(ds, engine_stream, args.chips)
+        _sharded(args.n, args.chips)
     print(f"peak_bytes_in_use after phases: {_peak_bytes(jax.devices()[0])}",
           flush=True)
     ok = device["platform"] == "tpu"

@@ -16,7 +16,9 @@ Two drivers over the same per-shard function:
   - ``mesh=None``: ``jax.vmap`` with a named axis — S arbitrary, runs on one
     host; this is also how the merge is unit-tested.
   - ``mesh=...``: ``shard_map`` over a 1-D device mesh (axis ``"shards"``),
-    one shard per device — the production layout.
+    one shard per device — the production layout. The first such search
+    moves the shard state onto the mesh for good (each device then holds
+    only its shard); the vmap driver reads that same placed state.
 
 Live mutation (docs/mutability.md) is threaded through the shards:
 ``upsert`` routes rows with the *global* centroid table (bitwise the same
@@ -36,6 +38,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import ivf as ivf_mod
 from repro.core import topk as topk_mod
@@ -155,8 +159,6 @@ def _mesh_driver(mesh: jax.sharding.Mesh, **static):
     ``shard_map`` program over ``mesh``, one shard per device — built once
     per (mesh, knobs), so a stream of searches compiles once instead of
     once per call."""
-    from jax.sharding import PartitionSpec as P
-
     fn = functools.partial(_local_search, **static)
 
     def per_device(cen, lists, real, gids, cb, base, mem, qq, fb, lv, nss,
@@ -261,9 +263,9 @@ class ShardedEngine:
         # optional persist.WALWriter, mirroring SearchEngine.attach_wal:
         # mutations are logged + fsync'd before the state swap
         self._wal = None
-        # (snapshot, mesh, placed snapshot, placed namespace table) of the
-        # last mesh search (_on_mesh)
-        self._mesh_copy = None
+        # the device mesh the state lives on (_place); None until the first
+        # mesh search
+        self._mesh = None
         # namespace membership sharded with the same round-robin permutation
         # as the lists: shard j's (n_ns, L) slice covers exactly its lists;
         # padding lists are member-False for every namespace
@@ -501,13 +503,13 @@ class ShardedEngine:
                 # durable before visible (docs/persistence.md)
                 self._wal.log_upsert(ids, vecs, avals)
             self._locator = loc
-            self._state = _ShardState(
+            self._state = self._place(_ShardState(
                 centroids_s=st.centroids_s, lists_s=lists_s,
                 real_s=st.real_s, base_s=base_s, gids_s=gids_s,
                 live_s=(pack_filter_mask(lists_s.ids >= 0)
                         if n_tomb else None),
                 rows_used=rows_used, epoch=st.epoch + 1,
-                n_tombstones=n_tomb)
+                n_tombstones=n_tomb))
         return assign
 
     def delete(self, ids) -> int:
@@ -535,11 +537,11 @@ class ShardedEngine:
                 # the same `found` set from the full batch
                 self._wal.log_delete(ids)
             self._locator = loc
-            self._state = st._replace(
+            self._state = self._place(st._replace(
                 lists_s=lists_s,
                 live_s=pack_filter_mask(lists_s.ids >= 0),
                 epoch=st.epoch + 1,
-                n_tombstones=st.n_tombstones + len(found))
+                n_tombstones=st.n_tombstones + len(found)))
             return len(found)
 
     def compact(self, cap: int | None = None) -> int:
@@ -609,57 +611,50 @@ class ShardedEngine:
             if self._wal is not None:
                 self._wal.log_compact(cap)
             self._locator = None
-            self._state = st._replace(
+            self._state = self._place(st._replace(
                 lists_s=lists_s,
                 base_s=None if st.base_s is None else jnp.asarray(n_base),
                 gids_s=st.gids_s if st.base_s is None else jnp.asarray(n_gids),
                 live_s=None, rows_used=tuple(rows_used),
-                epoch=st.epoch + 1, n_tombstones=0)
+                epoch=st.epoch + 1, n_tombstones=0))
             return reclaimed
 
-    def _on_mesh(self, st: _ShardState, mesh: jax.sharding.Mesh
-                 ) -> tuple[_ShardState, jax.Array | None]:
-        """``st`` and the namespace table with every shard-partitioned array
-        split over ``mesh``, one shard per device.
+    def _place(self, st: _ShardState) -> _ShardState:
+        """``st`` with every shard-partitioned array split over the engine's
+        mesh, one shard per device (a no-op for arrays already split so);
+        ``st`` as it is while the engine has no mesh."""
+        if self._mesh is None:
+            return st
+        sh = NamedSharding(self._mesh, P(AXIS))
+        return st._replace(
+            centroids_s=jax.device_put(st.centroids_s, sh),
+            lists_s=jax.device_put(st.lists_s, sh),
+            real_s=jax.device_put(st.real_s, sh),
+            base_s=jax.device_put(st.base_s, sh),
+            gids_s=jax.device_put(st.gids_s, sh),
+            live_s=jax.device_put(st.live_s, sh))
 
-        Placed once per (snapshot, mesh) and kept beside the engine state,
-        so a stream of mesh searches finds its shards already resident
-        instead of resharding every call. The state itself stays as built:
-        the vmap driver keeps reading unplaced arrays (arrays placed on an
-        explicit-axis mesh carry their sharding in their type, which the
-        vmap driver's scatters reject).
-        """
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        hit = self._mesh_copy
-        if hit is not None and hit[0] is st and hit[1] == mesh:
-            return hit[2], hit[3]
-        sh = NamedSharding(mesh, P(AXIS))
-
-        def put(x):
-            return None if x is None else jax.device_put(x, sh)
-
-        placed = st._replace(
-            centroids_s=put(st.centroids_s),
-            lists_s=jax.tree.map(put, st.lists_s), real_s=put(st.real_s),
-            base_s=put(st.base_s), gids_s=put(st.gids_s),
-            live_s=put(st.live_s))
-        member_s = put(self.member_s)
-        self._mesh_copy = (st, mesh, placed, member_s)
-        return placed, member_s
-
-    def shard_bytes(self, mesh: jax.sharding.Mesh) -> dict:
-        """Bytes of shard state each device holds for the shard_map driver
-        on ``mesh`` (placing it there if no search on ``mesh`` has yet)."""
-        placed, member_s = self._on_mesh(self._state, mesh)
-        held: dict = {}
-        for leaf in jax.tree.leaves((placed, member_s)):
-            if isinstance(leaf, jax.Array):
-                for shard in leaf.addressable_shards:
-                    held[shard.device] = (held.get(shard.device, 0)
-                                          + shard.data.nbytes)
-        return held
+    def _move_to(self, mesh: jax.sharding.Mesh) -> None:
+        """Move the state and the namespace table onto ``mesh`` for good:
+        the arrays are replaced, not copied, so each device holds only its
+        own shard, and every later mutation is placed there too. Both
+        drivers read the one placed state."""
+        if mesh.shape[AXIS] != self.num_shards:
+            raise ValueError(
+                f"mesh axis {AXIS!r} has {mesh.shape[AXIS]} devices but the "
+                f"engine holds {self.num_shards} shards")
+        # Auto axes: arrays on an Explicit-axis mesh (jax.make_mesh's
+        # default) carry their sharding in their type, and the vmap driver's
+        # gathers then refuse them (test_sharded_shard_map_on_device_mesh)
+        auto = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+        with self._mutate_lock:
+            if self._mesh == auto:
+                return
+            self._mesh = auto
+            self._state = self._place(self._state)
+            self.member_s = jax.device_put(self.member_s,
+                                           NamedSharding(auto, P(AXIS)))
 
     def search(self, queries: jax.Array, k: int = 10, *,
                nprobe: int | None = None, rerank_mult: int | None = None,
@@ -750,13 +745,10 @@ class ShardedEngine:
             return SearchResult(mvals[0], mids[0],
                                 QueryStats(*(s[0] for s in stats)))
 
-        if mesh.shape[AXIS] != self.num_shards:
-            raise ValueError(
-                f"mesh axis {AXIS!r} has {mesh.shape[AXIS]} devices but the "
-                f"engine holds {self.num_shards} shards")
-        st, placed_member = self._on_mesh(st, mesh)
-        member_s = placed_member if namespaces is not None else None
-        mvals, mids, stats = _mesh_driver(mesh, **static)(
+        self._move_to(mesh)
+        st = self._place(st)  # a no-op unless this search did the move
+        member_s = self.member_s if namespaces is not None else None
+        mvals, mids, stats = _mesh_driver(self._mesh, **static)(
             st.centroids_s, st.lists_s, st.real_s, st.gids_s, self.codebook,
             st.base_s, member_s, q, fbits_s, st.live_s, namespaces, tau)
         return SearchResult(mvals[0], mids[0], QueryStats(*(s[0] for s in stats)))

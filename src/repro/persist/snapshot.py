@@ -533,7 +533,7 @@ def _deserialize_sharded(directory: str, manifest: dict) -> ShardedEngine:
     engine._mutate_lock = threading.RLock()
     engine._locator = None
     engine._wal = None
-    engine._mesh_copy = None
+    engine._mesh = None
     return engine
 
 

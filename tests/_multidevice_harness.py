@@ -7,7 +7,9 @@ Asserts on a real 8-device host mesh:
   - the shard_map driver is bit-identical to the vmap driver (which needs
     no devices and is tested everywhere else), unfiltered and filtered;
   - mutation (delete/upsert/compact) threads through the multi-device
-    path: both drivers agree after every epoch and tombstones never leak.
+    path: both drivers agree after every epoch and tombstones never leak;
+  - after a mesh search, and after compaction, each device holds exactly
+    one shard of the engine's state.
 Exits 0 and prints OK on success; any assertion kills the process.
 """
 import os
@@ -50,7 +52,21 @@ def drivers_agree(tag):
     return rm
 
 
+def one_shard_per_device(tag):
+    """Every shard-partitioned array lives on the mesh, split so that each
+    device holds exactly one shard — no whole copy left on device 0."""
+    leaves = jax.tree.leaves((sh._state, sh.member_s))
+    arrays = [x for x in leaves if isinstance(x, jax.Array)]
+    assert arrays, tag
+    for x in arrays:
+        shards = x.addressable_shards
+        assert {s.device for s in shards} == set(mesh.devices.flat), tag
+        assert all(s.data.shape == (1,) + x.shape[1:] for s in shards), (
+            tag, x.shape, [s.data.shape for s in shards])
+
+
 r0 = drivers_agree("pristine")
+one_shard_per_device("pristine")
 assert (np.asarray(r0.stats.rows_tombstoned) == 0).all()
 
 # mutation over the 8-way mesh: delete / upsert / compact, re-checking the
@@ -69,6 +85,7 @@ drivers_agree("post-upsert")
 assert sh.compact() == 160
 assert sh.n_tombstones == 0
 r3 = drivers_agree("post-compact")
+one_shard_per_device("post-compact")
 assert (np.asarray(r3.stats.rows_tombstoned) == 0).all()
 assert not np.isin(np.asarray(r3.ids), dead).any()
 

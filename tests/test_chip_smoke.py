@@ -34,7 +34,7 @@ def test_chip_smoke_phases_pass_on_cpu_and_report_not_ok(capsys, monkeypatch,
     lines = capsys.readouterr().out.strip().splitlines()
     assert rc == 1
     for phase in ("device:", "build:", "stage parity:", "serve:",
-                  "serve vs reference: ids identical"):
+                  "serve vs reference: ids identical", "serve vs float64:"):
         assert any(line.startswith(phase) for line in lines), phase
     last = json.loads(lines[-1])
     assert last == {"ok": False, "device": {"platform": "cpu", "kind": "cpu",
