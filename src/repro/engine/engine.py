@@ -381,6 +381,11 @@ def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
               ) -> SearchResult:
     """The whole engine as one pure function (stages 1-4 + stats).
 
+    Each stage runs under a ``jax.named_scope`` (``coarse``, ``scan``, and
+    in ``finalize_candidates`` ``rerank`` and ``merge``, then ``stats``):
+    the scopes reach only the HLO metadata, where a profiler trace reads
+    them as each device op's ``tf_op`` path (docs/serving.md).
+
     ``filter_bits``/``namespaces``/``live_bits``/``margin_tau`` are *traced*
     arguments (None simply drops out of the trace): changing the predicate,
     tenant mix, tombstone set, or per-request margin budget between requests
@@ -400,24 +405,27 @@ def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
     sweep fill the autotuner should time against; early exit changes the
     stream kernel variant. ``margin_tau`` itself stays traced.
     """
-    probes, lists_pruned = coarse_probes(
-        coarse, q, nprobe=nprobe, ef=ef, ns_member=ns_member,
-        namespaces=namespaces, probe_policy=probe_policy,
-        margin_tau=margin_tau)
+    with jax.named_scope("coarse"):
+        probes, lists_pruned = coarse_probes(
+            coarse, q, nprobe=nprobe, ef=ef, ns_member=ns_member,
+            namespaces=namespaces, probe_policy=probe_policy,
+            margin_tau=margin_tau)
     # the selection budget stage 3+4 will take — under 'stream' this lets
     # the scan kernel reduce candidates in VMEM instead of writing the full
     # (Q, nprobe*cap) pool to HBM
-    flat_d, flat_ids, tiles_skipped = scan_candidates(
-        index, q, probes, scan_impl=scan_impl, keep=(r * k) if r else k,
-        filter_bits=combine_filter_bits(filter_bits, live_bits),
-        early_exit=early_exit,
-        probe_fill=(MARGIN_PROBE_FILL if probe_policy == "margin" else 1.0))
+    with jax.named_scope("scan"):
+        flat_d, flat_ids, tiles_skipped = scan_candidates(
+            index, q, probes, scan_impl=scan_impl, keep=(r * k) if r else k,
+            filter_bits=combine_filter_bits(filter_bits, live_bits),
+            early_exit=early_exit,
+            probe_fill=(MARGIN_PROBE_FILL if probe_policy == "margin"
+                        else 1.0))
     vals, out_ids, reranked = rerank_mod.finalize_candidates(
         flat_d, flat_ids, base, q, k, r, rerank_impl=rerank_impl)
-    return SearchResult(dists=vals, ids=out_ids,
-                        stats=make_stats(index, probes, reranked, filter_bits,
-                                         live_bits, lists_pruned,
-                                         tiles_skipped))
+    with jax.named_scope("stats"):
+        stats = make_stats(index, probes, reranked, filter_bits, live_bits,
+                           lists_pruned, tiles_skipped)
+    return SearchResult(dists=vals, ids=out_ids, stats=stats)
 
 
 # ONE process-wide jit: cache is keyed on static knobs + pytree structure +

@@ -63,23 +63,27 @@ def finalize_candidates(flat_d: jax.Array, flat_ids: jax.Array,
     trace time, like the scan dispatch).
     Returns (dists (Q, k), ids (Q, k), reranked (Q,) i32 work counter).
     Shared by the single-host engine and the per-shard pipeline so the two
-    paths cannot drift.
+    paths cannot drift. The pre-selection and the final top-k run under
+    ``jax.named_scope("merge")``, the refinement under ``"rerank"``.
     """
     if r:
         rr = min(r * k, flat_d.shape[1])
-        _, pos = topk_mod.masked_topk(flat_d, flat_ids >= 0, rr)
-        cand_ids = topk_mod.gather_ids(flat_ids, pos)
+        with jax.named_scope("merge"):
+            _, pos = topk_mod.masked_topk(flat_d, flat_ids >= 0, rr)
+            cand_ids = topk_mod.gather_ids(flat_ids, pos)
         impl, tile_r = ops.resolve_rerank_dispatch(
             rerank_impl, flat_d.shape[0], rr, q.shape[-1], k, base.shape[0])
-        if impl == "stream":
-            vals, out_ids = ops.rerank_stream_topk(base, q, cand_ids, k=k,
-                                                   tile_r=tile_r)
-        else:
-            vals, out_ids = exact_rerank(base, q, cand_ids, k)
-        reranked = jnp.sum((cand_ids >= 0).astype(jnp.int32), axis=1)
+        with jax.named_scope("rerank"):
+            if impl == "stream":
+                vals, out_ids = ops.rerank_stream_topk(base, q, cand_ids, k=k,
+                                                       tile_r=tile_r)
+            else:
+                vals, out_ids = exact_rerank(base, q, cand_ids, k)
+            reranked = jnp.sum((cand_ids >= 0).astype(jnp.int32), axis=1)
     else:
-        vals, pos = topk_mod.masked_topk(flat_d, flat_ids >= 0, k)
-        out_ids = topk_mod.gather_ids(flat_ids, pos)
+        with jax.named_scope("merge"):
+            vals, pos = topk_mod.masked_topk(flat_d, flat_ids >= 0, k)
+            out_ids = topk_mod.gather_ids(flat_ids, pos)
         reranked = jnp.zeros((flat_d.shape[0],), jnp.int32)
     return vals, out_ids, reranked
 

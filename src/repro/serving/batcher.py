@@ -27,6 +27,7 @@ from collections import deque
 from concurrent.futures import Future
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.errors import DeadlineExceeded, LoopClosed, Overloaded
 
@@ -161,7 +162,15 @@ class Batcher:
         that request's submit time) for more same-``k`` requests, then
         returns up to ``max(buckets)`` of them in FIFO order. Different-``k``
         requests stay queued and head the next batch.
+
+        The wait is the profiler span ``repro.batcher.next_batch``
+        (docs/serving.md): on the serving loop's thread, the time it spent
+        waiting for a head request and its co-riders.
         """
+        with TraceAnnotation("repro.batcher.next_batch"):
+            return self._next_batch(timeout)
+
+    def _next_batch(self, timeout: float | None) -> list[Request] | None:
         cap = self.buckets[-1]
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._cond:

@@ -39,6 +39,15 @@ terms; ``role='standby'`` replays the shipped stream into its engine
 the primary's heartbeat, and on ``promote()`` drains replay, bumps the
 fencing term, snapshots, and starts accepting mutations — the failover
 runbook in docs/serving.md walks the full drill.
+
+Tracing (docs/serving.md#tracing-a-live-loop): the dispatch thread records
+profiler spans (``jax.profiler.TraceAnnotation``) that cost nothing unless a
+``jax.profiler`` trace is active: ``repro.batcher.next_batch`` while it waits
+for requests, then one ``repro.serve.batch`` per dispatch (arguments
+``batch``, ``rows``, ``bucket``) around ``repro.serve.form``,
+``repro.serve.enqueue``, ``repro.serve.fetch``, ``repro.serve.resolve`` and
+``repro.serve.account``. ``metrics().queue_wait_s`` sums each served
+request's wait from submit to dequeue.
 """
 from __future__ import annotations
 
@@ -50,12 +59,13 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import persist
 from repro.engine import SearchEngine, fused_cache_size
 from repro.kernels.ops import (autotune_cache_size, load_autotune_cache,
                                save_autotune_cache)
-from repro.serving.batcher import DEFAULT_BUCKETS, Batcher, Request
+from repro.serving.batcher import DEFAULT_BUCKETS, Batcher, Request, bucket_for
 from repro.serving.errors import LoopClosed, NotPrimary, Overloaded
 from repro.serving.stats import StatsRegistry
 
@@ -110,6 +120,8 @@ class LoopMetrics(NamedTuple):
     segments_shipped: int = 0        # primary: WAL segments published
     records_replayed: int = 0        # standby: records applied from the
     #                                  shipped stream
+    queue_wait_s: float = 0.0        # sum over served rows of dequeue time -
+    #                                  submit time (time.monotonic)
 
 
 class ServingLoop:
@@ -246,6 +258,8 @@ class ServingLoop:
         self._tiles_skipped = 0
         self._auto_compactions = 0
         self._checkpoints = 0
+        self._queue_wait_s = 0.0
+        self._dispatched = 0  # the dispatch thread's batch sequence number
         self._dim = int(engine.index.centroids.shape[1])
 
     # -- lifecycle ----------------------------------------------------------
@@ -526,6 +540,7 @@ class ServingLoop:
                 rejects=self.batcher.rejects,
                 deadline_misses=self.batcher.deadline_misses,
                 checkpoints=self._checkpoints,
+                queue_wait_s=self._queue_wait_s,
             )
 
     # -- dispatch thread -----------------------------------------------------
@@ -769,44 +784,61 @@ class ServingLoop:
         return res
 
     def _dispatch(self, reqs: list[Request]) -> None:
-        padded, bucket = self.batcher.form(reqs)
+        t_deq = time.monotonic()  # the batch's dequeue, for queue_wait_s
         n = len(reqs)
-        ns = None
-        if self.engine.ns_member is not None:
-            # padding rows are unrestricted (-1): their results are dropped,
-            # so the cheapest trace-consistent value wins
-            ns = np.full((bucket,), -1, np.int32)
-            ns[:n] = [r.namespace for r in reqs]
-        res = self._call_engine(jnp.asarray(padded), reqs[0].k, namespaces=ns)
-        # one device->host sync for the whole batch
-        dists = np.asarray(res.dists)
-        ids = np.asarray(res.ids)
-        lp = np.asarray(res.stats.lists_probed)
-        cs = np.asarray(res.stats.codes_scanned)
-        rr = np.asarray(res.stats.reranked)
-        rf = np.asarray(res.stats.rows_filtered)
-        rt = np.asarray(res.stats.rows_tombstoned)
-        pr = np.asarray(res.stats.lists_pruned)
-        ts = np.asarray(res.stats.tiles_skipped)
-        t_done = time.monotonic()
-        lats = [t_done - r.t_submit for r in reqs]
+        seq = self._dispatched
+        self._dispatched += 1
+        with TraceAnnotation("repro.serve.batch", batch=seq, rows=n,
+                             bucket=bucket_for(n, self.batcher.buckets)):
+            self._serve_batch(reqs, t_deq)
 
-        for i, r in enumerate(reqs):
-            r.future.set_result(ServeResult(
-                dists=dists[i], ids=ids[i], lists_probed=int(lp[i]),
-                codes_scanned=int(cs[i]), reranked=int(rr[i]),
-                rows_filtered=int(rf[i]), rows_tombstoned=int(rt[i]),
-                lists_pruned=int(pr[i]), tiles_skipped=int(ts[i]),
-                latency_s=lats[i]))
+    def _serve_batch(self, reqs: list[Request], t_deq: float) -> None:
+        n = len(reqs)
+        with TraceAnnotation("repro.serve.form"):
+            padded, bucket = self.batcher.form(reqs)
+            ns = None
+            if self.engine.ns_member is not None:
+                # padding rows are unrestricted (-1): their results are
+                # dropped, so the cheapest trace-consistent value wins
+                ns = np.full((bucket,), -1, np.int32)
+                ns[:n] = [r.namespace for r in reqs]
+            q = jnp.asarray(padded)
+        with TraceAnnotation("repro.serve.enqueue"):
+            res = self._call_engine(q, reqs[0].k, namespaces=ns)
+        with TraceAnnotation("repro.serve.fetch"):
+            dists = np.asarray(res.dists)
+            ids = np.asarray(res.ids)
+            lp = np.asarray(res.stats.lists_probed)
+            cs = np.asarray(res.stats.codes_scanned)
+            rr = np.asarray(res.stats.reranked)
+            rf = np.asarray(res.stats.rows_filtered)
+            rt = np.asarray(res.stats.rows_tombstoned)
+            pr = np.asarray(res.stats.lists_pruned)
+            ts = np.asarray(res.stats.tiles_skipped)
+        with TraceAnnotation("repro.serve.resolve"):
+            t_done = time.monotonic()
+            lats = [t_done - r.t_submit for r in reqs]
+            for i, r in enumerate(reqs):
+                r.future.set_result(ServeResult(
+                    dists=dists[i], ids=ids[i], lists_probed=int(lp[i]),
+                    codes_scanned=int(cs[i]), reranked=int(rr[i]),
+                    rows_filtered=int(rf[i]), rows_tombstoned=int(rt[i]),
+                    lists_pruned=int(pr[i]), tiles_skipped=int(ts[i]),
+                    latency_s=lats[i]))
         # padding rows [n:] are dropped on the floor here — accounting and
         # callers only ever see rows [:n]
-        self.stats.record_batch([r.tenant for r in reqs], lp[:n], cs[:n],
-                                rr[:n], lats, rf[:n], rt[:n], pr[:n], ts[:n])
-        with self._lock:
-            self._batches += 1
-            self._rows_served += n
-            self._rows_padded += bucket - n
-            self._rows_tombstoned += int(rt[:n].sum())
-            self._lists_pruned += int(pr[:n].sum())
-            self._tiles_skipped += int(ts[:n].sum())
-            self._bucket_counts[bucket] = self._bucket_counts.get(bucket, 0) + 1
+        with TraceAnnotation("repro.serve.account"):
+            self.stats.record_batch([r.tenant for r in reqs], lp[:n], cs[:n],
+                                    rr[:n], lats, rf[:n], rt[:n], pr[:n],
+                                    ts[:n])
+            wait = n * t_deq - sum(r.t_submit for r in reqs)
+            with self._lock:
+                self._batches += 1
+                self._rows_served += n
+                self._rows_padded += bucket - n
+                self._rows_tombstoned += int(rt[:n].sum())
+                self._lists_pruned += int(pr[:n].sum())
+                self._tiles_skipped += int(ts[:n].sum())
+                self._bucket_counts[bucket] = (
+                    self._bucket_counts.get(bucket, 0) + 1)
+                self._queue_wait_s += wait
