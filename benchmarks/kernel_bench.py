@@ -196,11 +196,10 @@ def anytime_scan_traffic(q: int = 32, p: int = 16, cap: int = 1024,
     adp_probes, lists_pruned = margin_prune_probes(cvals, probes, tau)
 
     keep = 40
-    fix_d, fix_i = ivf.scan_probes_stream(index, qs, probes, keep=keep,
-                                          tile_n=tile)
-    adp_d, adp_i, skipped = ivf.scan_probes_stream(index, qs, adp_probes,
-                                                   keep=keep, tile_n=tile,
-                                                   early_exit=True)
+    fix_d, fix_i, _, _ = ivf.scan_probes_stream(index, qs, probes,
+                                                keep=keep, tile_n=tile)
+    adp_d, adp_i, skipped, _ = ivf.scan_probes_stream(
+        index, qs, adp_probes, keep=keep, tile_n=tile, early_exit=True)
 
     def _final10(dd, ii):
         v, pos = masked_topk(dd, ii >= 0, 10)

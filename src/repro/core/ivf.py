@@ -236,7 +236,11 @@ def scan_probes_stream(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
     at fixed ``keep``: excluded rows free their candidate slots instead of
     occupying them the way a post-filter would. Returns a *reduced*
     candidate pool (dists (Q, C') f32, ids (Q, C') i32, -1 = absent) with
-    C' = P * n_tiles * kc.
+    C' = P * n_tiles * kc, and two (Q,) i32 work counters:
+    ``tiles_skipped`` (early exit, below; zeros without it) and
+    ``tiles_scanned`` — the cap tiles the kernel ran ADC and selection on:
+    the live tiles (``fastscan_kernel.live_tiles``, the rule the kernel's
+    fetch plan applies) of the valid probes, less those early exit pruned.
 
     Exactness: any final selection of <= ``keep`` candidates per query over
     (dists, ids) — e.g. ``rerank.finalize_candidates`` with
@@ -247,19 +251,18 @@ def scan_probes_stream(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
     lowest-slot-first, matching ``masked_topk``'s lowest-flat-index
     tie-break.
 
-    ``early_exit`` arms the kernel's anytime tile pruning (docs/anytime.md)
-    and changes the return to (dists, ids, tiles_skipped (Q,) i32) — the
-    per-query count of valid-probe tiles whose scan (and usually DMA) the
-    lower bound proved irrelevant. The final <= ``keep`` selection stays
-    bit-identical; the raw pool does not (pruned tiles surface as absent
-    candidates).
+    ``early_exit`` arms the kernel's anytime tile pruning (docs/anytime.md):
+    ``tiles_skipped`` then counts, per query, the valid-probe tiles whose
+    scan the lower bound proved irrelevant. The final <= ``keep``
+    selection stays bit-identical; the raw pool does not (pruned tiles
+    surface as absent candidates).
     """
+    from repro.kernels import fastscan_kernel as fk
     from repro.kernels import ops
 
     qlut = probe_tables(index, q, probe_ids)          # (Q, P, M, 16)
     qq, p = probe_ids.shape
     bias_sum = jnp.sum(qlut.bias, axis=-1)             # (Q, P)
-    tiles_skipped = None
     if early_exit:
         vals, slots, skipped = ops.fastscan_stream_topk(
             qlut.table_q8.reshape(qq * p, *qlut.table_q8.shape[2:]),
@@ -268,14 +271,19 @@ def scan_probes_stream(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
             early_exit=True, groups_per_query=p,
             scales=qlut.scale.reshape(-1),
             biases=bias_sum.reshape(-1))               # + (G, n_tiles)
-        tiles_skipped = jnp.sum(skipped.reshape(qq, -1), axis=1)
     else:
         vals, slots = ops.fastscan_stream_topk(
             qlut.table_q8.reshape(qq * p, *qlut.table_q8.shape[2:]),
             index.lists.codes, probe_ids.reshape(-1), index.lists.sizes,
             keep=keep, tile_n=tile_n,
             filter_bits=filter_bits)                   # (G, n_tiles, kc) x2
+        skipped = jnp.zeros(vals.shape[:2], jnp.int32)
     n_tiles, kc = vals.shape[1], vals.shape[2]
+    live = fk.live_tiles(probe_ids.reshape(-1), index.lists.sizes, n_tiles,
+                         index.lists.cap // n_tiles)      # (G, n_tiles)
+    scanned = live & (skipped == 0)
+    tiles_skipped = jnp.sum(skipped.reshape(qq, -1), axis=1)
+    tiles_scanned = jnp.sum(scanned.reshape(qq, -1), axis=1, dtype=jnp.int32)
     vals = vals.reshape(qq, p, n_tiles * kc)
     slots = slots.reshape(qq, p, n_tiles * kc)
     valid = slots >= 0
@@ -289,9 +297,8 @@ def scan_probes_stream(index: IVFIndex, q: jax.Array, probe_ids: jax.Array, *,
     lids = jnp.maximum(probe_ids, 0)[..., None]
     ids = index.lists.ids[lids, jnp.maximum(slots, 0)]
     ids = jnp.where(valid & (probe_ids >= 0)[..., None], ids, -1)
-    if early_exit:
-        return dists.reshape(qq, -1), ids.reshape(qq, -1), tiles_skipped
-    return dists.reshape(qq, -1), ids.reshape(qq, -1)
+    return (dists.reshape(qq, -1), ids.reshape(qq, -1), tiles_skipped,
+            tiles_scanned)
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "topk"))

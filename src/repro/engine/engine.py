@@ -118,6 +118,13 @@ class QueryStats(NamedTuple):
     tiles_skipped: jax.Array  # (Q,) i32  valid-probe cap tiles the stream
     #                           kernel's early exit proved irrelevant; 0
     #                           without early_exit or on gathered impls
+    tiles_scanned: jax.Array  # (Q,) i32  cap tiles the stream kernel ran
+    #                           ADC and selection on: the valid probes'
+    #                           tiles that hold a row, sum ceil(size/tile),
+    #                           less those early exit pruned (tiles past a
+    #                           list's occupancy cost no copy and no scan);
+    #                           0 on gathered impls. ServingLoop never
+    #                           fetches it
 
 
 class SearchResult(NamedTuple):
@@ -232,12 +239,14 @@ def scan_candidates(index: ivf_mod.IVFIndex, q: jax.Array, probes: jax.Array,
                     *, scan_impl: str, keep: int | None = None,
                     filter_bits: jax.Array | None = None,
                     early_exit: bool = False, probe_fill: float = 1.0
-                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Stage 2 — quantized scan, flattened to one candidate pool per query.
 
     Returns (dists (Q, C) f32, ids (Q, C) i32 with -1 = pad,
     tiles_skipped (Q,) i32 — early-exit counter, zeros unless the stream
-    path ran with ``early_exit=True``). With the gathered impls
+    path ran with ``early_exit=True`` — and tiles_scanned (Q,) i32, the
+    stream kernel's scanned cap tiles, zeros on the gathered impls). With
+    the gathered impls
     C = nprobe*cap. ``keep`` is the per-query candidate budget the
     downstream selection will take (r*k, or k without re-rank): when the
     resolved impl is 'stream' and ``keep`` is given, the scan runs gather-
@@ -270,14 +279,10 @@ def scan_candidates(index: ivf_mod.IVFIndex, q: jax.Array, probes: jax.Array,
             2 * index.lists.codes.shape[-1], nlist=index.lists.nlist,
             probe_fill=probe_fill)
         if impl == "stream":
-            out = ivf_mod.scan_probes_stream(index, q, probes, keep=keep,
-                                             tile_n=tile_n,
-                                             filter_bits=filter_bits,
-                                             early_exit=early_exit)
-            if early_exit:
-                return out
-            dists, ids = out
-            return dists, ids, jnp.zeros((dists.shape[0],), jnp.int32)
+            return ivf_mod.scan_probes_stream(index, q, probes, keep=keep,
+                                              tile_n=tile_n,
+                                              filter_bits=filter_bits,
+                                              early_exit=early_exit)
     dists, ids = ivf_mod.scan_probes(index, q, probes, impl=scan_impl)
     if filter_bits is not None:
         # post-filter oracle: (Q, P, cap) bool of rows that pass
@@ -287,8 +292,8 @@ def scan_candidates(index: ivf_mod.IVFIndex, q: jax.Array, probes: jax.Array,
         dists = jnp.where(ok, dists, jnp.inf)
         ids = jnp.where(ok, ids, -1)
     qq = dists.shape[0]
-    return (dists.reshape(qq, -1), ids.reshape(qq, -1),
-            jnp.zeros((qq,), jnp.int32))
+    zeros = jnp.zeros((qq,), jnp.int32)
+    return dists.reshape(qq, -1), ids.reshape(qq, -1), zeros, zeros
 
 
 def combine_filter_bits(filter_bits: jax.Array | None,
@@ -349,13 +354,16 @@ def make_stats(index: ivf_mod.IVFIndex, probes: jax.Array,
                filter_bits: jax.Array | None = None,
                live_bits: jax.Array | None = None,
                lists_pruned: jax.Array | None = None,
-               tiles_skipped: jax.Array | None = None) -> QueryStats:
+               tiles_skipped: jax.Array | None = None,
+               tiles_scanned: jax.Array | None = None) -> QueryStats:
     """Work counters from the probe set + the re-rank stage's counter.
 
     ``lists_pruned``/``tiles_skipped`` are the anytime counters
-    (docs/anytime.md); None (the hand-composition default) records zeros.
+    (docs/anytime.md), ``tiles_scanned`` the scan stage's own; None (the
+    hand-composition default) records zeros.
     """
     qq = probes.shape[0]
+    zeros = jnp.zeros((qq,), jnp.int32)
     return QueryStats(
         lists_probed=jnp.sum((probes >= 0).astype(jnp.int32), axis=1),
         codes_scanned=jnp.sum(index.lists.probed_sizes(probes), axis=1),
@@ -363,10 +371,9 @@ def make_stats(index: ivf_mod.IVFIndex, probes: jax.Array,
         rows_filtered=count_rows_filtered(index, probes, filter_bits,
                                           live_bits),
         rows_tombstoned=count_rows_tombstoned(index, probes, live_bits),
-        lists_pruned=(jnp.zeros((qq,), jnp.int32) if lists_pruned is None
-                      else lists_pruned),
-        tiles_skipped=(jnp.zeros((qq,), jnp.int32) if tiles_skipped is None
-                       else tiles_skipped),
+        lists_pruned=zeros if lists_pruned is None else lists_pruned,
+        tiles_skipped=zeros if tiles_skipped is None else tiles_skipped,
+        tiles_scanned=zeros if tiles_scanned is None else tiles_scanned,
     )
 
 
@@ -414,7 +421,7 @@ def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
     # the scan kernel reduce candidates in VMEM instead of writing the full
     # (Q, nprobe*cap) pool to HBM
     with jax.named_scope("scan"):
-        flat_d, flat_ids, tiles_skipped = scan_candidates(
+        flat_d, flat_ids, tiles_skipped, tiles_scanned = scan_candidates(
             index, q, probes, scan_impl=scan_impl, keep=(r * k) if r else k,
             filter_bits=combine_filter_bits(filter_bits, live_bits),
             early_exit=early_exit,
@@ -424,7 +431,7 @@ def _pipeline(coarse, index: ivf_mod.IVFIndex, base: jax.Array | None,
         flat_d, flat_ids, base, q, k, r, rerank_impl=rerank_impl)
     with jax.named_scope("stats"):
         stats = make_stats(index, probes, reranked, filter_bits, live_bits,
-                           lists_pruned, tiles_skipped)
+                           lists_pruned, tiles_skipped, tiles_scanned)
     return SearchResult(dists=vals, ids=out_ids, stats=stats)
 
 
@@ -789,8 +796,8 @@ class SearchEngine:
     def scan(self, q: jax.Array, probe_ids: jax.Array
              ) -> tuple[jax.Array, jax.Array]:
         """Stage 2 — quantized scan: flattened ADC candidates per query."""
-        dists, ids, _ = scan_candidates(self.index, q, probe_ids,
-                                        scan_impl=self.config.scan_impl)
+        dists, ids, _, _ = scan_candidates(self.index, q, probe_ids,
+                                           scan_impl=self.config.scan_impl)
         return dists, ids
 
     # -- the unified entry points ------------------------------------------

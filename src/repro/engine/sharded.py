@@ -110,7 +110,7 @@ def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
     # filter: ANDed in so the stream kernel's candidate budget skips them
     # before selection.
     eff = combine_filter_bits(fbits, live)
-    flat_d, flat_ids, tiles_skipped = scan_candidates(
+    flat_d, flat_ids, tiles_skipped, tiles_scanned = scan_candidates(
         index, q, probes, scan_impl=scan_impl, keep=(r * k) if r else k,
         filter_bits=eff, early_exit=early_exit,
         probe_fill=(MARGIN_PROBE_FILL if probe_policy == "margin" else 1.0))
@@ -149,6 +149,7 @@ def _local_search(centroids, lists: ListStore, real, gids, codebook, base,
         rows_tombstoned=jax.lax.psum(rows_tombstoned, AXIS),
         lists_pruned=jax.lax.psum(lists_pruned, AXIS),
         tiles_skipped=jax.lax.psum(tiles_skipped, AXIS),
+        tiles_scanned=jax.lax.psum(tiles_scanned, AXIS),
     )
     return mvals, mids, stats
 

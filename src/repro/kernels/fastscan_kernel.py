@@ -44,8 +44,11 @@ Variant D — ``stream`` (gather-free probe streaming):
     reduction: instead of writing the full ``(G, cap)`` accumulation back to
     HBM it keeps a per-tile partial selection in VMEM and emits only
     ``(G, n_tiles, kc)`` (quantized dist, slot) candidate pairs — shrinking
-    the scan-stage writeback by ~cap/kc. Pallas double-buffers the block
-    copies: tile t+1 streams in while tile t is scanned.
+    the scan-stage writeback by ~cap/kc. It also reads each list's
+    occupancy: a cap tile that starts at or past it holds no row
+    (``live_tiles``), so it is treated like an invalid probe — no copy, no
+    ADC, no selection, only the sentinel row. Pallas double-buffers the
+    block copies: tile t+1 streams in while tile t is scanned.
 
 All kernels are tiled with explicit BlockSpecs, laid out for Mosaic (the
 TPU kernel compiler), which lowers only blocks whose last two dims are
@@ -389,7 +392,23 @@ def fastscan_blockmin(table_q8: jax.Array, packed_codes: jax.Array, *,
 ACC_SENTINEL = jnp.iinfo(jnp.int32).max
 
 
-def _fetch_plan(probe_ids: jax.Array, n_tiles: int) -> jax.Array:
+def live_tiles(probe_ids: jax.Array, sizes: jax.Array, n_tiles: int,
+               tile_n: int) -> jax.Array:
+    """(G,) probe ids x (nlist,) occupancy -> (G, n_tiles) bool.
+
+    True where the cap tile holds at least one row of a valid probe's list:
+    tile ``t`` of list ``l`` is live iff ``t * tile_n < sizes[l]``. The one
+    liveness rule the fetch plan, the top-k kernels and the
+    ``tiles_scanned`` counter (``core.ivf.scan_probes_stream``) share.
+    """
+    occ = jnp.where(probe_ids >= 0, sizes[jnp.maximum(probe_ids, 0)], 0)
+    starts = jnp.arange(n_tiles, dtype=jnp.int32) * tile_n
+    return starts[None, :] < occ[:, None]
+
+
+def _fetch_plan(probe_ids: jax.Array, n_tiles: int,
+                sizes: jax.Array | None = None, tile_n: int = 0
+                ) -> jax.Array:
     """(G,) probe ids -> (G*n_tiles,) i32 source step per sequential step.
 
     Grid steps run group-major: step ``s`` is group ``s // n_tiles``, cap
@@ -397,10 +416,15 @@ def _fetch_plan(probe_ids: jax.Array, n_tiles: int) -> jax.Array:
     block of the nearest earlier valid step (or, before the first valid
     step, of that first one), so its code block index equals its
     neighbour's and the block pipeline issues no copy for it: invalid
-    probes cost no HBM traffic.
+    probes cost no HBM traffic. Given ``sizes`` (the top-k kernels), a
+    step is valid only if its tile is also live (``live_tiles``), so tiles
+    past a list's occupancy cost no copy either.
     """
     steps = jnp.arange(probe_ids.shape[0] * n_tiles, dtype=jnp.int32)
-    valid = jnp.repeat(probe_ids >= 0, n_tiles)
+    if sizes is None:
+        valid = jnp.repeat(probe_ids >= 0, n_tiles)
+    else:
+        valid = live_tiles(probe_ids, sizes, n_tiles, tile_n).reshape(-1)
     last = jax.lax.cummax(jnp.where(valid, steps, -1))
     first = jnp.argmax(valid).astype(jnp.int32)
     return jnp.where(last >= 0, last, first)
@@ -557,6 +581,13 @@ def _masked_tile_acc(table_ref, codes_ref, sizes_ref, lid, fbits_ref, *,
     return acc
 
 
+def _tile_live(sizes_ref, lid, ni, tile_n: int):
+    """In-kernel ``live_tiles``: a valid probe whose tile ``ni`` starts
+    below its list's occupancy. ``lid`` is clamped for the SMEM read; an
+    invalid probe is dead whatever list 0 holds."""
+    return (lid >= 0) & (ni * tile_n < sizes_ref[jnp.maximum(lid, 0)])
+
+
 def _stream_topk_kernel(probe_ref, sizes_ref, src_ref, table_ref, *rest,
                         tile_n: int, kc: int, has_filter: bool):
     """Stream kernel + fused per-tile candidate selection (+ optional
@@ -566,7 +597,10 @@ def _stream_topk_kernel(probe_ref, sizes_ref, src_ref, table_ref, *rest,
     global slot ids within the list (-1 = absent), as row ``ni`` of the
     group's (1, n_tiles, kc) blocks. Slots past the list's true occupancy
     (``sizes_ref``) are masked to ACC_SENTINEL *before* the selection, so
-    padding can never displace a real candidate. With ``has_filter`` this
+    padding can never displace a real candidate; a tile with no occupied
+    slot at all is not scanned (its copy was never issued, see
+    ``_fetch_plan``) and emits the all-sentinel row its selection would
+    have produced, exactly as an invalid probe does. With ``has_filter`` this
     tile's packed filter words (``fbits_ref``, (1, 1, W_t) u8, LSB-first —
     see core/lists.py) ride into VMEM next to the LUT; rows whose bit is 0
     are masked through the *same* pre-selection path as the occupancy mask,
@@ -582,8 +616,9 @@ def _stream_topk_kernel(probe_ref, sizes_ref, src_ref, table_ref, *rest,
         fbits_ref = None
     ni = pl.program_id(1)
     lid = probe_ref[pl.program_id(0)]
+    live = _tile_live(sizes_ref, lid, ni, tile_n)
 
-    @pl.when(lid >= 0)
+    @pl.when(live)
     def _scan():
         acc = _masked_tile_acc(table_ref, codes_ref, sizes_ref, lid,
                                fbits_ref, tile_n=tile_n, ni=ni)
@@ -591,8 +626,8 @@ def _stream_topk_kernel(probe_ref, sizes_ref, src_ref, table_ref, *rest,
         _store_row(vals_ref, ni, vals)
         _store_row(slots_ref, ni, slots)
 
-    @pl.when(lid < 0)
-    def _skip():
+    @pl.when(~live)
+    def _skip():  # invalid probe or dead tile: no copy, no scan
         _store_row(vals_ref, ni, jnp.full((1, kc), ACC_SENTINEL, jnp.int32))
         _store_row(slots_ref, ni, jnp.full((1, kc), -1, jnp.int32))
 
@@ -647,11 +682,13 @@ def _stream_topk_prune_kernel(probe_ref, sizes_ref, src_ref, table_ref,
     with downstream's lowest-index tie-break the final top-kc is unchanged
     (bit-identical for kc == keep). A skipped tile saves its scan and
     selection; its copy was already issued by the block pipeline, whose
-    index maps cannot see the running threshold.
+    index maps cannot see the running threshold. A dead tile (past its
+    list's occupancy) is neither copied nor scanned nor folded, whatever
+    the bound says: all its candidates would be +inf.
 
     Third output ``skip_ref`` (1, n_tiles, 1) i32: row ``ni`` is 1 iff this
-    (group, tile) held a valid probe but was pruned (its emitted candidates
-    are sentinels).
+    (group, tile) held a valid probe but its bound failed, live or dead
+    (its emitted candidates are sentinels).
     """
     del src_ref  # read by the index maps only
     if has_filter:
@@ -669,7 +706,8 @@ def _stream_topk_prune_kernel(probe_ref, sizes_ref, src_ref, table_ref,
         run_ref[...] = jnp.full_like(run_ref, jnp.inf)
         thr_ref[0] = jnp.float32(jnp.inf)
 
-    do_scan = (lid >= 0) & (bounds_ref[gi] < thr_ref[0])
+    fits = bounds_ref[gi] < thr_ref[0]
+    do_scan = _tile_live(sizes_ref, lid, ni, tile_n) & fits
 
     @pl.when(do_scan)
     def _scan():
@@ -689,11 +727,11 @@ def _stream_topk_prune_kernel(probe_ref, sizes_ref, src_ref, table_ref,
         thr_ref[0] = jnp.max(merged)  # ascending: the kc-th best
 
     @pl.when(~do_scan)
-    def _skip():  # invalid probe, or a tile the bound proved irrelevant
+    def _skip():  # invalid probe, dead tile, or the bound proved irrelevant
         _store_row(vals_ref, ni, jnp.full((1, kc), ACC_SENTINEL, jnp.int32))
         _store_row(slots_ref, ni, jnp.full((1, kc), -1, jnp.int32))
-        _store_row(skip_ref, ni,
-                   jnp.full((1, 1), 1, jnp.int32) * (lid >= 0).astype(jnp.int32))
+        pruned = ((lid >= 0) & ~fits).astype(jnp.int32)
+        _store_row(skip_ref, ni, jnp.full((1, 1), 1, jnp.int32) * pruned)
 
 
 def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
@@ -717,8 +755,10 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     Returns (vals (G, n_tiles, kc) i32, slots (G, n_tiles, kc) i32): per
     (group, cap-tile) the kc smallest quantized distances and their slot
     position inside the probed list, -1 slot = absent (padding past the
-    list's occupancy, a filtered-out row, or an invalid probe — whose copy
-    is skipped outright).
+    list's occupancy, a filtered-out row, or an invalid probe). Invalid
+    probes and tiles that start at or past their list's occupancy
+    (``live_tiles``) cost no copy and no scan: only their sentinel row is
+    written.
 
     The full (G, cap) accumulation never reaches HBM: selection happens in
     VMEM on the tile just copied in, so scan-stage writeback shrinks
@@ -732,12 +772,13 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     With ``early_exit`` (anytime search, docs/anytime.md) the kernel also
     prunes tiles whose group-level lower bound on any dequantized distance
     can't beat the query's running kc-th best — skipping the tile's scan
-    and selection (its copy is already issued by then). Requires
+    and selection (a live tile's copy is already issued by then). Requires
     ``groups_per_query`` (consecutive groups per query, > 0, dividing G) and
     the per-group dequantization affine ``scales``/``biases`` ((G,) f32,
     exactly what downstream selection applies). Returns a third array
-    ``skipped`` (G, n_tiles) i32, 1 per pruned valid-probe tile. The final
-    top-kc per query is bit-identical to the unpruned kernel; the raw
+    ``skipped`` (G, n_tiles) i32, 1 per valid-probe tile whose bound
+    failed (dead tiles included, though they cost nothing either way). The
+    final top-kc per query is bit-identical to the unpruned kernel; the raw
     candidate pool is not (pruned tiles emit sentinels).
     """
     g, m, k = table_q8.shape
@@ -751,7 +792,7 @@ def fastscan_stream_topk_grouped(table_q8: jax.Array, list_codes: jax.Array,
     in_specs = [
         pl.BlockSpec((1, 2 * k, mh), lambda gi, ni, *_: (gi, 0, 0)),
     ]
-    operands = [probes, sizes, _fetch_plan(probes, n_tiles),
+    operands = [probes, sizes, _fetch_plan(probes, n_tiles, sizes, tile_n),
                 _lut_planes(table_q8)]
     if early_exit:
         assert groups_per_query > 0 and g % groups_per_query == 0, (

@@ -315,11 +315,14 @@ def fastscan_stream_topk(table_q8: jax.Array, list_codes: jax.Array,
     tile_n)`` smallest entries, so any final selection of <= ``keep``
     candidates per query is exact (see the kernel docstring for the
     tie-break argument). ``sizes`` (nlist,) i32 masks slots past each
-    list's true occupancy before selection. ``filter_bits`` — optional
-    (nlist, W) u8 packed filter bitmap (``core.lists.pack_filter_mask``
-    layout) — masks rows whose bit is 0 through the same pre-selection
-    path; only the probed groups' rows (a (G, W) u8 gather, ~1.5% of the
-    code bytes at M=16) ever reach the kernel. Returns
+    list's true occupancy before selection, and a cap tile that starts at
+    or past it (``fastscan_kernel.live_tiles``) costs no copy and no
+    scan: it emits the all-absent row an invalid probe emits.
+    ``filter_bits`` — optional (nlist, W) u8 packed filter bitmap
+    (``core.lists.pack_filter_mask`` layout) — masks rows whose bit is 0
+    through the same pre-selection path; only the probed groups' rows (a
+    (G, W) u8 gather, ~1.5% of the code bytes at M=16) ever reach the
+    kernel. Returns
     (vals (G, n_tiles, kc) i32, slots (G, n_tiles, kc) i32, -1 = absent —
     padding, filtered-out, or invalid probe).
 

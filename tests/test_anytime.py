@@ -187,13 +187,11 @@ def _topk_oracle(dists, ids, keep):
 
 def _assert_early_exit_lossless(index, q, probes, keep, tile_n,
                                 filter_bits=None):
-    base_d, base_i = ivf.scan_probes_stream(index, q, probes, keep=keep,
-                                            tile_n=tile_n,
-                                            filter_bits=filter_bits)
-    ee_d, ee_i, skipped = ivf.scan_probes_stream(index, q, probes, keep=keep,
-                                                 tile_n=tile_n,
-                                                 filter_bits=filter_bits,
-                                                 early_exit=True)
+    base_d, base_i, _, _ = ivf.scan_probes_stream(
+        index, q, probes, keep=keep, tile_n=tile_n, filter_bits=filter_bits)
+    ee_d, ee_i, skipped, _ = ivf.scan_probes_stream(
+        index, q, probes, keep=keep, tile_n=tile_n, filter_bits=filter_bits,
+        early_exit=True)
     want_v, want_i = _topk_oracle(base_d, base_i, keep)
     got_v, got_i = _topk_oracle(ee_d, ee_i, keep)
     np.testing.assert_array_equal(got_i, want_i)
@@ -584,11 +582,11 @@ def test_scan_candidates_gathered_early_exit_is_noop():
     rng = np.random.default_rng(10)
     q = jnp.asarray(rng.normal(size=(2, 32)).astype(np.float32))
     probes = jnp.asarray(np.array([[0, 2], [4, 1]], np.int32))
-    d_ref, i_ref, ts_ref = scan_candidates(index, q, probes, scan_impl="ref",
-                                           keep=5, early_exit=True)
+    d_ref, i_ref, ts_ref, _ = scan_candidates(
+        index, q, probes, scan_impl="ref", keep=5, early_exit=True)
     np.testing.assert_array_equal(np.asarray(ts_ref), 0)
-    d_st, i_st, ts_st = scan_candidates(index, q, probes, scan_impl="stream",
-                                        keep=5, early_exit=True)
+    d_st, i_st, ts_st, _ = scan_candidates(
+        index, q, probes, scan_impl="stream", keep=5, early_exit=True)
     assert np.asarray(ts_st).shape == (2,)
     want_v, want_i = _topk_oracle(d_ref, i_ref, 5)
     got_v, got_i = _topk_oracle(d_st, i_st, 5)

@@ -158,8 +158,8 @@ def test_stream_scan_filter_parity_vs_oracle(selectivity):
     mask = _random_mask(index, selectivity)
     fb = pack_filter_mask(jnp.asarray(mask))
     keep = 20
-    ds, ids_s = ivf.scan_probes_stream(index, q, probes, keep=keep, tile_n=32,
-                                       filter_bits=fb)
+    ds, ids_s, _, _ = ivf.scan_probes_stream(index, q, probes, keep=keep,
+                                             tile_n=32, filter_bits=fb)
     vals_s, pos_s = masked_topk(ds, ids_s >= 0, keep)
     got_ids = gather_ids(ids_s, pos_s)
     want_vals, want_ids = _oracle_select(index, q, probes, mask, keep)
@@ -176,8 +176,8 @@ def test_filter_with_ragged_occupancy_and_invalid_probes():
     mask = _random_mask(index, 0.5, seed=11)
     fb = pack_filter_mask(jnp.asarray(mask))
     keep = 12
-    ds, ids_s = ivf.scan_probes_stream(index, q, probes, keep=keep, tile_n=16,
-                                       filter_bits=fb)
+    ds, ids_s, _, _ = ivf.scan_probes_stream(index, q, probes, keep=keep,
+                                             tile_n=16, filter_bits=fb)
     vals_s, pos_s = masked_topk(ds, ids_s >= 0, keep)
     got_ids = gather_ids(ids_s, pos_s)
     want_vals, want_ids = _oracle_select(index, q, probes, mask, keep)
@@ -190,8 +190,8 @@ def test_all_filtered_lists_return_only_sentinels():
     q = _queries(index, 2)
     probes = jnp.asarray(np.array([[0, 1, 2], [3, 4, 5]], np.int32))
     fb = pack_filter_mask(jnp.zeros_like(index.lists.ids, dtype=bool))
-    ds, ids_s = ivf.scan_probes_stream(index, q, probes, keep=10, tile_n=16,
-                                       filter_bits=fb)
+    ds, ids_s, _, _ = ivf.scan_probes_stream(index, q, probes, keep=10,
+                                             tile_n=16, filter_bits=fb)
     assert np.all(np.asarray(ids_s) == -1)
 
 

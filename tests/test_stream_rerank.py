@@ -23,7 +23,8 @@ import pytest
 
 from repro.core import topk as topk_mod
 from repro.data import vectors
-from repro.engine import EngineConfig, SearchEngine, ShardedEngine
+from repro.engine import (EngineConfig, QueryStats, SearchEngine,
+                          ShardedEngine)
 from repro.engine import rerank as rerank_mod
 from repro.kernels import ops, ref
 from repro.launch.hlo_analysis import xla_cost_dict
@@ -189,8 +190,12 @@ def test_search_stream_rerank_bitidentical(scan_impl):
                                       np.asarray(res_ref.ids))
         np.testing.assert_array_equal(np.asarray(res.dists),
                                       np.asarray(res_ref.dists))
-        for a, b in zip(res.stats, res_ref.stats):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for name in QueryStats._fields:
+            if name == "tiles_scanned" and scan_impl == "stream":
+                continue  # the stream scan's own counter (0 on 'ref')
+            np.testing.assert_array_equal(np.asarray(getattr(res.stats, name)),
+                                          np.asarray(getattr(res_ref.stats,
+                                                             name)))
 
 
 def test_sharded_stream_rerank_matches_gathered_vmap_driver():
